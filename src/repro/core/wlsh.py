@@ -64,6 +64,50 @@ class BuiltGroup:
     codes: np.ndarray  # (n, beta) int32 raw codes (dense path / export)
 
 
+def _bucket_slices(sorted_codes: np.ndarray, q_codes: np.ndarray,
+                   width: int) -> tuple[np.ndarray, np.ndarray]:
+    """Per table, the slice [lo, hi) of its ascending codes that holds the
+    query's bucket of this width: codes in [b_lo, b_lo + width) with
+    ``b_lo = (q_code // width) * width``.
+
+    The bounds are searched inclusively and clipped to the codes' dtype,
+    so a wide bucket never casts (and copies) the table.
+    """
+    info = np.iinfo(sorted_codes.dtype)
+    b_lo = (q_codes.astype(np.int64) // width) * width
+    first = np.maximum(b_lo, info.min).astype(sorted_codes.dtype)
+    last = np.minimum(b_lo + (width - 1), info.max).astype(sorted_codes.dtype)
+    lo = np.array([np.searchsorted(t, v, side="left")
+                   for t, v in zip(sorted_codes, first)], dtype=np.int64)
+    hi = np.array([np.searchsorted(t, v, side="right")
+                   for t, v in zip(sorted_codes, last)], dtype=np.int64)
+    return lo, hi
+
+
+def _level_collisions(sorted_codes: np.ndarray, sorted_ids: np.ndarray,
+                      q_codes: np.ndarray, c: int, n_levels: int):
+    """Yield, for levels j = 0..n_levels, the ids that newly collide with
+    the query at level j: one entry per (table, point) whose code enters
+    the query's level-j bucket, ``code // c**j == q_code // c**j``.
+
+    Level buckets nest (``(x // c**j) // c == x // c**(j+1)``), so each
+    table's slice only widens and the new entries are the two rings
+    around the previous level's slice: summed over levels, every point's
+    count is the number of tables it collides with at that level.
+    """
+    prev_lo = prev_hi = None
+    for j in range(n_levels + 1):
+        lo, hi = _bucket_slices(sorted_codes, q_codes, c**j)
+        if prev_lo is None:
+            prev_lo = prev_hi = lo
+        parts = []
+        for t in range(len(lo)):
+            parts.append(sorted_ids[t, lo[t]:prev_lo[t]])
+            parts.append(sorted_ids[t, prev_hi[t]:hi[t]])
+        yield np.concatenate(parts) if parts else sorted_ids[:0, 0]
+        prev_lo, prev_hi = lo, hi
+
+
 class WLSHIndex:
     """Multi-weight (c, k)-WNN index over one data set.
 
@@ -251,40 +295,15 @@ class WLSHIndex:
         checked = np.zeros(n, dtype=bool)
         cand_ids: list[np.ndarray] = []
         cand_dists: list[np.ndarray] = []
-        lo = np.empty(beta_i, dtype=np.int64)
-        hi = np.empty(beta_i, dtype=np.int64)
-        prev_lo = np.zeros(beta_i, dtype=np.int64)
-        prev_hi = np.zeros(beta_i, dtype=np.int64)
-        first = True
         n_collisions = 0
         n_checked = 0
         n_good = 0
         stop_level = n_levels
         found_k = False
 
-        for j in range(n_levels + 1):
-            l = c**j
-            b_lo = (q_codes // l) * l  # level-j bucket = codes in [b_lo, b_lo+l)
-            newly: list[np.ndarray] = []
-            for t in range(beta_i):
-                lo[t] = np.searchsorted(sc[t], b_lo[t], side="left")
-                hi[t] = np.searchsorted(sc[t], b_lo[t] + l, side="left")
-                if first:
-                    seg = sids[t, lo[t] : hi[t]]
-                    if seg.size:
-                        newly.append(seg)
-                else:
-                    left = sids[t, lo[t] : prev_lo[t]]
-                    right = sids[t, prev_hi[t] : hi[t]]
-                    if left.size:
-                        newly.append(left)
-                    if right.size:
-                        newly.append(right)
-            first = False
-            prev_lo[:] = lo
-            prev_hi[:] = hi
-            if newly:
-                inc = np.concatenate(newly)
+        for j, inc in enumerate(
+                _level_collisions(sc, sids, q_codes, c, n_levels)):
+            if inc.size:
                 n_collisions += inc.size
                 np.add.at(counts, inc, 1)
             # identify frequent, not-yet-checked candidates
@@ -337,13 +356,23 @@ class WLSHIndex:
         self, q: np.ndarray, weight_id: int, k: int = 1,
         c: float | None = None,
     ) -> SearchResult:
-        """Single-pass dense search (the TPU formulation, numpy oracle).
+        """Dense search semantics (the TPU formulation), numpy oracle.
 
-        Computes jmin per (point, table), takes the mu-th order statistic to
-        get L_freq, then applies the paper's stop conditions level-by-level
-        analytically.  Must agree with ``search`` on the candidate *sets*;
-        used to validate kernels and the sharded engine.  ``c`` optionally
-        overrides the configured approximation ratio (see ``_c_eff``).
+        A point's L_freq is the first level j at which at least mu of the
+        member's first beta_{W_i} tables collide (``code // c**j`` equal):
+        the mu-th order statistic of its per-table first-collision levels.
+        The paper's stop conditions then apply level by level to *every*
+        point with L_freq <= j (``search`` instead checks candidates in
+        discovery order up to the budget).  Must agree with ``search`` on
+        the candidate *sets*; used to validate kernels and the sharded
+        engine.  ``c`` optionally overrides the configured approximation
+        ratio (see ``_c_eff``).
+
+        The per-point collision counts grow level by level through the
+        same bucket walk as ``search`` (``_level_collisions``) and work
+        stops at the stop level, so the cost is the buckets probed, not
+        n x beta per level; ``tests/test_wlsh.py`` pins this to the dense
+        per-(point, table) formula field for field.
         """
         built, slot, beta_i, mu_i = self._member_params(weight_id)
         plan = built.plan
@@ -356,35 +385,29 @@ class WLSHIndex:
 
         q = np.asarray(q, dtype=np.float32)
         q_codes = hash_codes_np(q[None, :], built.fam)[0][:beta_i]
-        codes = built.codes[:, :beta_i]
-
-        jmin = np.full((n, beta_i), n_levels + 1, dtype=np.int16)
-        a = codes.astype(np.int64).copy()
-        b = q_codes.astype(np.int64).copy()
-        for j in range(n_levels + 1):
-            eq = (a == b[None, :]) & (jmin > n_levels)
-            jmin[eq] = j
-            a //= c
-            b //= c
-        if mu_i > beta_i:
-            l_freq = np.full(n, n_levels + 1, dtype=np.int16)
-        else:
-            l_freq = np.partition(jmin, mu_i - 1, axis=1)[:, mu_i - 1]
-
-        dists = weighted_lp_np(self.data, q, w_i, self.cfg.p)
+        counts = np.zeros(n, dtype=np.int64)  # tables colliding at level j
+        dists = np.empty(n)  # filled as points become frequent
+        freq = np.zeros(n, dtype=bool)
+        n_collisions = 0
         stop_level, n_checked, found_k = n_levels, 0, False
-        for j in range(n_levels + 1):
-            freq = l_freq <= j
-            n_freq = int(np.sum(freq))
-            n_chk = min(n_freq, budget)
+        for j, inc in enumerate(_level_collisions(
+                built.sorted_codes[:beta_i], built.sorted_ids[:beta_i],
+                q_codes, c, n_levels)):
+            n_collisions += inc.size
+            counts += np.bincount(inc, minlength=n)
+            new = np.where((counts >= mu_i) & ~freq)[0]
+            if new.size:
+                dists[new] = weighted_lp_np(self.data[new], q, w_i,
+                                            self.cfg.p)
+                freq[new] = True
+            idx = np.where(freq)[0]
+            n_chk = min(idx.size, budget)
             R = r_min * (c**j)
-            n_good = int(np.sum(freq & (dists <= c * R)))
+            n_good = int(np.sum(dists[idx] <= c * R))
             if n_good >= k or n_chk >= budget:
                 stop_level, n_checked, found_k = j, n_chk, n_good >= k
                 break
             n_checked = n_chk
-        freq = l_freq <= stop_level
-        idx = np.where(freq)[0]
         top = idx[np.argsort(dists[idx], kind="stable")[:k]]
         out_ids = np.full(k, -1, dtype=np.int64)
         out_d = np.full(k, np.inf)
@@ -393,7 +416,7 @@ class WLSHIndex:
         stats = SearchStats(
             stop_level=stop_level,
             n_checked=n_checked,
-            n_collisions=int(np.sum(jmin <= stop_level)),
+            n_collisions=n_collisions,
             io_blocks=float("nan"),
             found_k=found_k,
         )

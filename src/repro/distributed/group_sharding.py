@@ -47,7 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding
 
-from .sharding import named_sharding
+from .sharding import make_mesh, named_sharding
 
 __all__ = [
     "HostShardedState",
@@ -83,10 +83,10 @@ def serving_mesh(n_shards: int = 1, *, axis: str = "data") -> Mesh:
             f"XLA_FLAGS=--xla_force_host_platform_device_count={n_shards}"
         )
     if axis == "model":
-        return jax.make_mesh((1, n_shards), ("data", "model"))
+        return make_mesh((1, n_shards), ("data", "model"))
     if axis != "data":
         raise ValueError(f"shard axis must be 'data' or 'model', got {axis!r}")
-    return jax.make_mesh((n_shards, 1), ("data", "model"))
+    return make_mesh((n_shards, 1), ("data", "model"))
 
 
 def state_shardings(mesh: Mesh, cfg):

@@ -30,12 +30,13 @@ import warnings
 from typing import Iterable
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = [
     "spec",
     "shard",
     "shard_map_nocheck",
+    "make_mesh",
     "named_sharding",
     "with_rules",
     "axis_size",
@@ -43,21 +44,23 @@ __all__ = [
 
 
 def shard_map_nocheck(f, mesh: Mesh, in_specs, out_specs):
-    """shard_map with replication checking off, across jax versions.
-
-    jax >= 0.5 exports shard_map at top level (flag named check_vma);
-    0.4.x ships it under jax.experimental with check_rep.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map  # jax 0.4.x
-
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+    """``jax.shard_map`` with the varying-manual-axes check off."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
     )
+
+
+def make_mesh(shape: tuple[int, ...], names: tuple[str, ...]) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``.
+
+    ``jax.make_mesh`` makes Explicit axes by default, under which sharding
+    constraints and the serving state's in-place row updates are refused;
+    the program's meshes rely on the compiler propagating shardings.
+    """
+    return jax.make_mesh(shape, names,
+                         axis_types=(AxisType.Auto,) * len(shape))
+
 
 _DEFAULT_RULES: dict[str | None, tuple[str, ...]] = {
     "batch": ("pod", "data"),

@@ -27,9 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 __all__ = ["freq_level_pallas"]
 
 
@@ -96,7 +93,7 @@ def freq_level_pallas(
         out_specs=pl.BlockSpec((1, bn), lambda iq, ip: (iq, ip)),
         out_shape=jax.ShapeDtypeStruct((q, n), jnp.int32),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
     )(

@@ -15,9 +15,13 @@ bound by.  Two modes, one per engine pass:
                    for the engine's running top-k.
 
 Grid: (Q, block/BN).  Query code row (1, beta) and point codes (BN, beta)
-stay whole in the lane axis, as do the (1, d)/(BN, d) vector tiles; the
-p = 2 distance runs the norms+matmul expansion on the MXU inside the
-kernel (two (1, d) x (d, BN) contractions), p != 2 is a VPU reduction.
+stay whole in the lane axis, as do the (1, d)/(BN, d) vector tiles.
+Per-query operands and outputs are passed as (Q, 1, X) with the query
+axis squeezed out of the block, so every block's last two dims equal the
+array's and Mosaic accepts any Q (a (1, X) block of a (Q, X) array is
+refused once Q > 1).  The p = 2 distance runs the norms+matmul
+expansion on the MXU inside the kernel (two (1, d) x (d, BN)
+contractions), p != 2 is a VPU reduction.
 VMEM per grid step at BN=256, beta<=1024, d<=1024: ~1 MB codes + ~1 MB
 vectors + ~128 KB histogram scratch.  Per-query scalars (mu, beta_q,
 r_min / stop) ride in SMEM; the block's global row offset and the
@@ -37,10 +41,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams
-_CompilerParams = (getattr(pltpu, "CompilerParams", None)
-                   or pltpu.TPUCompilerParams)
 
 __all__ = ["fused_query_hist_pallas", "fused_query_scores_pallas", "nbins"]
 
@@ -86,12 +86,16 @@ def _lf_and_dist(cq_ref, cp_ref, qpt_ref, ppt_ref, w_ref, mu_ref, bq_ref,
     if abs(p - 2.0) < 1e-9:
         w2 = w * w
         qw2 = jnp.sum(w2 * qv * qv)
+        # full f32 contractions: the expansion cancels large terms, so a
+        # bf16 pass would move distances across good-level boundaries
         cross = jax.lax.dot_general(
             w2 * qv, x, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32,
         )  # (1, BN)
         onorm = jax.lax.dot_general(
             w2, x * x, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32,
         )  # (1, BN)
         d2 = qw2 - 2.0 * cross + onorm
@@ -158,23 +162,39 @@ def _scores_kernel(cq_ref, cp_ref, qpt_ref, ppt_ref, w_ref, mu_ref, bq_ref,
 def _specs(beta: int, d: int, bn: int):
     """Common in_specs prefix: codes/vectors/weight tiles + SMEM scalars."""
     smem_q = pl.BlockSpec(
-        (1, 1), lambda iq, ip: (iq, 0), memory_space=pltpu.SMEM
+        (None, 1, 1), lambda iq, ip: (iq, 0, 0), memory_space=pltpu.SMEM
     )
     smem_g = pl.BlockSpec(
         (1, 1), lambda iq, ip: (0, 0), memory_space=pltpu.SMEM
     )
     tiles = [
-        pl.BlockSpec((1, beta), lambda iq, ip: (iq, 0)),
+        pl.BlockSpec((None, 1, beta), lambda iq, ip: (iq, 0, 0)),
         pl.BlockSpec((bn, beta), lambda iq, ip: (ip, 0)),
-        pl.BlockSpec((1, d), lambda iq, ip: (iq, 0)),
+        pl.BlockSpec((None, 1, d), lambda iq, ip: (iq, 0, 0)),
         pl.BlockSpec((bn, d), lambda iq, ip: (ip, 0)),
-        pl.BlockSpec((1, d), lambda iq, ip: (iq, 0)),  # per-query weight
+        pl.BlockSpec((None, 1, d), lambda iq, ip: (iq, 0, 0)),  # weight
     ]
     return tiles, smem_q, smem_g
 
 
 def _as_col(v, dtype):
     return jnp.asarray(v, dtype).reshape(-1, 1)
+
+
+def _per_query_scalar(v, dtype):
+    """(Q,) -> (Q, 1, 1): one SMEM scalar per grid step (as ``_per_query_rows``)."""
+    return jnp.asarray(v, dtype).reshape(-1, 1, 1)
+
+
+def _per_query_rows(x, dtype):
+    """(Q, X) -> (Q, 1, X): one query row per grid step.
+
+    Mosaic requires a block's last two dims to be (8, 128)-aligned or to
+    equal the array's; a (1, X) block of a (Q, X) array is neither once
+    Q > 1.  The leading query axis is squeezed out of the block instead,
+    so each kernel invocation still sees a (1, X) row.
+    """
+    return x.astype(dtype).reshape(x.shape[0], 1, x.shape[1])
 
 
 @functools.partial(
@@ -211,9 +231,9 @@ def fused_query_hist_pallas(
         n_rows=int(n_rows), n_tiles=n_tiles, n_bins=n_bins,
     )
     tiles, smem_q, smem_g = _specs(beta, d, bn)
-    out_spec = pl.BlockSpec((1, n_bins), lambda iq, ip: (iq, 0))
-    out_shape = jax.ShapeDtypeStruct((q, n_bins), jnp.int32)
-    return pl.pallas_call(
+    out_spec = pl.BlockSpec((None, 1, n_bins), lambda iq, ip: (iq, 0, 0))
+    out_shape = jax.ShapeDtypeStruct((q, 1, n_bins), jnp.int32)
+    hf, hg = pl.pallas_call(
         kernel,
         grid=(q, n_tiles),
         in_specs=tiles + [smem_q, smem_q, smem_q, smem_g, smem_g],
@@ -224,21 +244,22 @@ def fused_query_hist_pallas(
             pltpu.VMEM((1, n_bins), jnp.int32),
         ],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
     )(
-        codes_q.astype(jnp.int32),
+        _per_query_rows(codes_q, jnp.int32),
         codes_p.astype(jnp.int32),
-        queries.astype(jnp.float32),
+        _per_query_rows(queries, jnp.float32),
         points.astype(jnp.float32),
-        q_weight.astype(jnp.float32),
-        _as_col(mu, jnp.int32),
-        _as_col(beta_q, jnp.int32),
-        _as_col(r_min, jnp.float32),
+        _per_query_rows(q_weight, jnp.float32),
+        _per_query_scalar(mu, jnp.int32),
+        _per_query_scalar(beta_q, jnp.int32),
+        _per_query_scalar(r_min, jnp.float32),
         _as_col(boff, jnp.int32),
         _as_col(n_valid, jnp.int32),
     )
+    return hf.reshape(q, n_bins), hg.reshape(q, n_bins)
 
 
 @functools.partial(
@@ -273,25 +294,26 @@ def fused_query_scores_pallas(
         n_rows=int(n_rows),
     )
     tiles, smem_q, smem_g = _specs(beta, d, bn)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=(q, b_pad // bn),
         in_specs=tiles + [smem_q, smem_q, smem_q, smem_g, smem_g],
-        out_specs=pl.BlockSpec((1, bn), lambda iq, ip: (iq, ip)),
-        out_shape=jax.ShapeDtypeStruct((q, b_pad), jnp.float32),
+        out_specs=pl.BlockSpec((None, 1, bn), lambda iq, ip: (iq, 0, ip)),
+        out_shape=jax.ShapeDtypeStruct((q, 1, b_pad), jnp.float32),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
     )(
-        codes_q.astype(jnp.int32),
+        _per_query_rows(codes_q, jnp.int32),
         codes_p.astype(jnp.int32),
-        queries.astype(jnp.float32),
+        _per_query_rows(queries, jnp.float32),
         points.astype(jnp.float32),
-        q_weight.astype(jnp.float32),
-        _as_col(mu, jnp.int32),
-        _as_col(beta_q, jnp.int32),
-        _as_col(stop, jnp.int32),
+        _per_query_rows(q_weight, jnp.float32),
+        _per_query_scalar(mu, jnp.int32),
+        _per_query_scalar(beta_q, jnp.int32),
+        _per_query_scalar(stop, jnp.int32),
         _as_col(boff, jnp.int32),
         _as_col(n_valid, jnp.int32),
     )
+    return out.reshape(q, b_pad)

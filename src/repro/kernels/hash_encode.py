@@ -24,9 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 __all__ = ["hash_encode_pallas"]
 
 
@@ -91,7 +88,7 @@ def hash_encode_pallas(
         out_shape=jax.ShapeDtypeStruct((n, beta), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bn, bb), jnp.float32)],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
     )(

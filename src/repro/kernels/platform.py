@@ -3,9 +3,8 @@
 One process serves one backend, so the backend query is answered once and
 cached (``backend()``) instead of re-asking ``jax.default_backend()`` on
 every op call — the seed-era ``ops.on_tpu()`` did exactly that re-query in
-the middle of every kernel dispatch.  ``set_platform`` (bayespec style,
-SNIPPETS.md snippet 1) pins the platform *before* the first JAX call and
-installs the GPU latency-hiding XLA flags; it also resets the cache.
+the middle of every kernel dispatch.  The platform itself is never pinned
+in code: JAX picks it (``JAX_PLATFORMS`` selects one explicitly).
 
 ``resolve`` maps the single user-facing knob — ``use_pallas`` on
 ``IndexConfig`` / ``ServiceConfig`` / the launcher's ``--use-pallas`` —
@@ -20,9 +19,7 @@ onto the concrete query-pipeline path.  The dispatch table for the
   gpu           fused                       XLA composite (Pallas once
                                             ``gpu_pallas_supported()``;
                                             the bodies are Mosaic/TPU
-                                            today, so not yet) — plus
-                                            the latency-hiding XLA flags
-                                            from ``set_platform``
+                                            today, so not yet)
   cpu           fused                       XLA composite (one jit, no
                                             per-stage HBM round trips)
   ============  ==========================  ===========================
@@ -37,7 +34,6 @@ backend.
 from __future__ import annotations
 
 import dataclasses
-import os
 
 import jax
 
@@ -49,17 +45,7 @@ __all__ = [
     "gpu_pallas_supported",
     "on_tpu",
     "resolve",
-    "set_platform",
 ]
-
-# <https://jax.readthedocs.io/en/latest/gpu_performance_tips.html> — the
-# latency-hiding scheduler + async collectives let state restores/prefetch
-# uploads overlap query launches on GPU the way they already do on TPU.
-_GPU_XLA_FLAGS = (
-    "--xla_gpu_triton_gemm_any=True "
-    "--xla_gpu_enable_latency_hiding_scheduler=true "
-    "--xla_gpu_enable_highest_priority_async_stream=true"
-)
 
 _backend_cache: str | None = None
 
@@ -70,7 +56,7 @@ def backend() -> str:
     The answer cannot change after the first JAX computation, so every op
     dispatch reads this cache instead of re-querying the JAX client
     registry (``ops.on_tpu()`` used to call ``jax.default_backend()`` per
-    op call).  ``set_platform`` resets the cache.
+    op call).
     """
     global _backend_cache
     if _backend_cache is None:
@@ -81,26 +67,6 @@ def backend() -> str:
 def on_tpu() -> bool:
     """True when the cached backend is TPU."""
     return backend() == "tpu"
-
-
-def set_platform(platform: str | None = None) -> None:
-    """Pin the JAX platform ("cpu" / "gpu" / "tpu") before first use.
-
-    Only takes effect ahead of the first JAX computation (JAX fixes its
-    client then).  On GPU additionally installs the latency-hiding XLA
-    flags (appended to any existing ``XLA_FLAGS``), mirroring the
-    bayespec ``set_platform`` helper.  Resets the cached ``backend()``.
-    """
-    global _backend_cache
-    if platform is not None:
-        jax.config.update("jax_platform_name", platform)
-        if platform == "gpu":
-            existing = os.environ.get("XLA_FLAGS", "")
-            if "--xla_gpu_enable_latency_hiding_scheduler" not in existing:
-                os.environ["XLA_FLAGS"] = (
-                    f"{existing} {_GPU_XLA_FLAGS}".strip()
-                )
-    _backend_cache = None
 
 
 def gpu_pallas_supported() -> bool:

@@ -42,6 +42,11 @@ __all__ = [
     "fused_query_scores_ref",
 ]
 
+# The p = 2 expansion cancels large terms; its contractions run in full
+# f32 on every backend (a TPU's default rounds f32 operands through bf16),
+# matching the fused kernel's.
+_F32 = jax.lax.Precision.HIGHEST
+
 
 @functools.partial(jax.jit, static_argnames=())
 def hash_encode_ref(points, proj, b_int, b_frac, weight, width):
@@ -115,7 +120,7 @@ def weighted_lp_ref(queries, points, weight, p: float):
     if abs(p - 2.0) < 1e-9:
         qq = jnp.sum(qw * qw, axis=-1)
         pp = jnp.sum(pw * pw, axis=-1)
-        cross = qw @ pw.T
+        cross = jnp.matmul(qw, pw.T, precision=_F32)
         d2 = qq[:, None] + pp[None, :] - 2.0 * cross
         return jnp.sqrt(jnp.maximum(d2, 0.0))
     diff = jnp.abs(qw[:, None, :] - pw[None, :, :])
@@ -136,8 +141,8 @@ def per_query_l2(q, w, pts):
     """(Q, B) weighted l2 with per-query weights, via two matmuls (MXU)."""
     w2 = w * w
     qw2 = jnp.sum(w2 * q * q, axis=-1)  # (Q,)
-    cross = (w2 * q) @ pts.T  # (Q, B)
-    onorm = w2 @ (pts * pts).T  # (Q, B)
+    cross = jnp.matmul(w2 * q, pts.T, precision=_F32)  # (Q, B)
+    onorm = jnp.matmul(w2, (pts * pts).T, precision=_F32)  # (Q, B)
     d2 = qw2[:, None] - 2.0 * cross + onorm
     return jnp.sqrt(jnp.maximum(d2, 0.0))
 

@@ -22,9 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 __all__ = ["weighted_lp_pallas"]
 
 
@@ -85,7 +82,7 @@ def weighted_lp_pallas(
         out_shape=jax.ShapeDtypeStruct((qn, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, bn), jnp.float32)],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
     )(

@@ -9,6 +9,8 @@ from __future__ import annotations
 
 import jax
 
+from ..distributed.sharding import make_mesh
+
 __all__ = ["make_production_mesh", "make_host_mesh"]
 
 
@@ -17,7 +19,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     axis (DCN) for the multi-pod dry-run."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int | None = None, model: int = 1):
@@ -25,4 +27,4 @@ def make_host_mesh(data: int | None = None, model: int = 1):
     n = len(jax.devices())
     if data is None:
         data = n // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))

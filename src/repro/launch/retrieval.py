@@ -49,8 +49,7 @@ Steps:
               switch the observability layer on (bit-exact either way):
               per-query trace spans to JSONL, the unified metrics
               registry as Prometheus text or JSON, and per-signature
-              compile/dispatch attribution (plus a jax.profiler capture
-              when available).
+              compile/dispatch attribution (plus a jax.profiler capture).
               ``--recall-sample-rate`` shadow-samples live queries for
               exact-oracle recall estimation; ``--health`` prints the
               per-rung observed-recall and alert report and
@@ -64,9 +63,12 @@ start without re-planning.
 from __future__ import annotations
 
 import argparse
+import os
+import pathlib
 import re
 import time
 
+import jax
 import numpy as np
 
 from ..core.datagen import make_dataset, make_weight_set
@@ -87,7 +89,10 @@ from ..serving.scheduler import (
     replay_with_driver,
 )
 
-__all__ = ["parse_bytes", "parse_ladder", "parse_tenants", "run", "main"]
+__all__ = ["parse_bytes", "parse_ladder", "parse_tenants", "run", "main",
+           "use_compilation_cache"]
+
+_REPO_ROOT = pathlib.Path(__file__).resolve().parents[3]
 
 _UNITS = {"": 1, "B": 1, "KB": 1 << 10, "MB": 1 << 20, "GB": 1 << 30,
           "TB": 1 << 40,
@@ -257,8 +262,11 @@ def _finish_obs(args, svc) -> dict | None:
         return None
     b = svc.batcher
     out: dict = {}
-    if b.profiler is not None:
-        b.profiler.stop_trace()
+    if args.profile_dir:
+        if not b.profiler.stop_trace():
+            raise RuntimeError("--profile-dir: no jax.profiler trace was "
+                               "running at the end of the serve phase")
+        print(f"obs: jax.profiler trace -> {args.profile_dir}")
     if b.tracer is not None:
         out["n_spans_started"] = b.tracer.n_started
         out["n_spans_finished"] = b.tracer.n_finished
@@ -389,7 +397,9 @@ def run(args) -> dict:
     svc = RetrievalService(plan, data, cfg=scfg)
     if obs and args.profile_dir:
         svc.batcher.profiler.profile_dir = args.profile_dir
-        svc.batcher.profiler.start_trace()
+        if not svc.batcher.profiler.start_trace():
+            raise RuntimeError("--profile-dir: the jax.profiler trace "
+                               "did not start")
     svc.warmup()
     t_build = time.time() - t0
     cache0 = svc.cache_summary()
@@ -509,6 +519,8 @@ def run(args) -> dict:
         "async": async_report,
         "obs": obs_report,
         "health": health_report,
+        "service": svc,
+        "result": res,
     }
 
 
@@ -617,6 +629,7 @@ def _serve_mixed(args, svc, plan, rng, qpts, wids, t_plan, t_build):
         "obs": obs_report,
         "health": health_report,
         "driver": driver.stats.summary() if driver is not None else None,
+        "service": svc,
     }
 
 
@@ -720,8 +733,8 @@ def parse_args(argv=None):
     ap.add_argument("--profile-dir", default=None, metavar="DIR",
                     help="observability: per-shape-signature compile and "
                          "dispatch-time attribution, plus a jax.profiler "
-                         "trace captured into DIR when the profiler is "
-                         "available; implies the obs layer on")
+                         "trace captured into DIR; implies the obs layer "
+                         "on")
     ap.add_argument("--recall-sample-rate", type=float, default=0.0,
                     metavar="RATE",
                     help="quality telemetry: shadow-sample this fraction "
@@ -782,7 +795,23 @@ def parse_args(argv=None):
     return args
 
 
+def use_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own setting and is
+    used as is.  Otherwise the cache lives at the fixed
+    ``<repo>/.jax_cache``, so a later run finds what an earlier one
+    compiled.  Called by entry points, never on import.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(_REPO_ROOT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def main(argv=None):
+    use_compilation_cache()
     return run(parse_args(argv))
 
 

@@ -24,6 +24,7 @@ import numpy as np
 from ..configs.base import get_config, reduced as reduce_cfg
 from ..distributed.fault import (PreemptionHandler, RestartSupervisor,
                                  StragglerMonitor)
+from ..distributed.sharding import make_mesh
 from ..models import build_model, init_params
 from ..training.checkpoint import CheckpointManager
 from ..training.data import DataConfig, SyntheticStream
@@ -39,7 +40,7 @@ def _mesh_or_none(spec: str):
         return None
     shape = tuple(int(x) for x in spec.split(","))
     names = ("data", "model")[: len(shape)]
-    return jax.make_mesh(shape, names)
+    return make_mesh(shape, names)
 
 
 def train(args) -> dict:

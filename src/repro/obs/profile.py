@@ -11,12 +11,11 @@ signature per (shape bucket, rung, shard count, kernel path).  The
   launch, per signature.
 
 Both are host-side bookkeeping and never touch device values, so
-enabling them is bit-exact.  When ``jax.profiler`` is importable the
-dispatch scope additionally opens a ``TraceAnnotation`` region (so
-launches are labeled in a captured device trace), ``start_trace`` /
-``stop_trace`` bracket an on-demand profiler capture, and
-``save_memory_snapshot`` writes a device-memory profile — all guarded:
-a missing or stubbed ``jax.profiler`` degrades to timing-only.
+enabling them is bit-exact.  The dispatch scope also opens a
+``jax.profiler.TraceAnnotation`` region, so launches are labeled in a
+captured device trace, and ``start_trace`` / ``stop_trace`` bracket an
+on-demand ``jax.profiler`` capture.  A capture that fails to start or
+stop raises: a requested trace is never silently missing.
 """
 
 from __future__ import annotations
@@ -25,16 +24,9 @@ import contextlib
 import threading
 import time
 
+import jax
+
 __all__ = ["Profiler"]
-
-
-def _jax_profiler():
-    """``jax.profiler`` when importable, else None (timing-only mode)."""
-    try:
-        from jax import profiler
-        return profiler
-    except Exception:
-        return None
 
 
 class Profiler:
@@ -63,16 +55,9 @@ class Profiler:
     @contextlib.contextmanager
     def dispatch(self, sig: str):
         """Time one compiled-step launch, annotated in device traces."""
-        prof = _jax_profiler()
-        ctx = contextlib.nullcontext()
-        if prof is not None:
-            try:
-                ctx = prof.TraceAnnotation(f"wlsh_query_step[{sig}]")
-            except Exception:
-                ctx = contextlib.nullcontext()
         t0 = self._timer()
         try:
-            with ctx:
+            with jax.profiler.TraceAnnotation(f"wlsh_query_step[{sig}]"):
                 yield
         finally:
             dt = self._timer() - t0
@@ -81,38 +66,26 @@ class Profiler:
                 self._dispatch_n[sig] = self._dispatch_n.get(sig, 0) + 1
 
     def start_trace(self) -> bool:
-        """Start a ``jax.profiler`` trace into ``profile_dir`` if possible."""
-        prof = _jax_profiler()
-        if prof is None or self.profile_dir is None or self._tracing:
+        """Start a ``jax.profiler`` trace into ``profile_dir``.
+
+        Returns False (and starts nothing) without a ``profile_dir`` or
+        while a trace is already running; a failed start raises.
+        """
+        if self.profile_dir is None or self._tracing:
             return False
-        try:
-            prof.start_trace(self.profile_dir)
-        except Exception:
-            return False
+        jax.profiler.start_trace(self.profile_dir)
         self._tracing = True
         return True
 
     def stop_trace(self) -> bool:
-        """Stop an in-flight ``jax.profiler`` trace, if one is running."""
-        prof = _jax_profiler()
-        if prof is None or not self._tracing:
+        """Stop the in-flight trace; False when none is running.
+
+        A failed stop (the trace not written) raises.
+        """
+        if not self._tracing:
             return False
         self._tracing = False
-        try:
-            prof.stop_trace()
-        except Exception:
-            return False
-        return True
-
-    def save_memory_snapshot(self, path: str) -> bool:
-        """On-demand device-memory profile to ``path`` (best effort)."""
-        prof = _jax_profiler()
-        if prof is None:
-            return False
-        try:
-            prof.save_device_memory_profile(path)
-        except Exception:
-            return False
+        jax.profiler.stop_trace()
         return True
 
     def summary(self) -> dict:

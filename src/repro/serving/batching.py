@@ -352,8 +352,13 @@ def merge_topk(ids, dists, extra_ids, extra_dists, k, drop=None):
     dists = np.atleast_2d(np.asarray(dists, np.float32))
     extra_ids = np.atleast_2d(np.asarray(extra_ids)).astype(np.int64)
     extra_dists = np.atleast_2d(np.asarray(extra_dists, np.float32))
-    cand_ids = np.concatenate([ids, extra_ids], axis=1)
-    cand_d = np.concatenate([dists, extra_dists], axis=1)
+    short = max(0, k - ids.shape[1] - extra_ids.shape[1])
+    rows = ids.shape[0]
+    cand_ids = np.concatenate(
+        [ids, extra_ids, np.full((rows, short), -1, np.int64)], axis=1)
+    cand_d = np.concatenate(
+        [dists, extra_dists, np.full((rows, short), np.inf, np.float32)],
+        axis=1)
     invalid = cand_ids < 0
     if drop:
         tomb = np.fromiter(drop, np.int64, count=len(drop))

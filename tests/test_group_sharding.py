@@ -92,6 +92,17 @@ def test_serving_mesh_validates_device_count():
     assert mesh.axis_names == ("data", "model") and mesh.size == 1
 
 
+@pytest.mark.parametrize("axis", ["data", "model"])
+def test_serving_mesh_axes_are_auto(axis):
+    """The serving mesh leaves sharding to the compiler (Auto axes):
+    under ``jax.make_mesh``'s Explicit default, compaction's in-place row
+    update of a resident state is refused."""
+    from jax.sharding import AxisType
+
+    mesh = group_sharding.serving_mesh(1, axis=axis)
+    assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
+
+
 def test_host_row_ranges_cover_capacity_evenly():
     assert group_sharding.host_row_ranges(1008, 8) == [
         (s * 126, (s + 1) * 126) for s in range(8)

@@ -46,7 +46,8 @@ def test_sharded_engine_matches_host_oracle_on_8_devices():
         cfg = PlanConfig(p=2.0, c=3, n=len(data), gamma_n=100.0)
         host = WLSHIndex(data, weights, cfg, tau=500.0, v=4, v_prime=4,
                          seed=9)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh(4, 2)
         gi = int(host.part.group_of[0])
         built = host._group(gi)
         icfg = IndexConfig(
@@ -102,7 +103,8 @@ def test_retrieval_service_on_8_devices_matches_host_oracle():
                          seed=9)
         plan = host.export_serving_plan()
         assert plan.n_groups >= 3
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh(4, 2)
         svc = RetrievalService(plan, data, mesh=mesh,
                                cfg=ServiceConfig(k=3, q_batch=4))
         rng = np.random.default_rng(43)
@@ -147,7 +149,8 @@ def test_train_step_spmd_matches_single_device():
         _, met0 = jax.jit(make_train_step(m0, ocfg))(s0, batch)
 
         # 4x2 mesh
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh(4, 2)
         m1 = build_model(cfg, mesh=mesh)
         p1 = init_params(m1.defs(), jax.random.PRNGKey(0))
         s1 = init_train_state(m1.defs(), p1, ocfg)
@@ -180,7 +183,8 @@ def test_dryrun_cell_on_8_device_mesh():
         from repro.training.train_loop import (batch_shardings,
             make_train_step, train_state_defs)
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh(4, 2)
         cfg = reduced(get_config("olmoe_1b_7b"))
         shape = ShapeConfig("s", 64, 8, "train")
         model = build_model(cfg, mesh=mesh)

@@ -9,6 +9,7 @@ import pytest
 from repro.core.c2lsh import C2LSH
 from repro.core.datagen import make_dataset, make_query_set, make_weight_set
 from repro.core.distances import weighted_lp_np
+from repro.core.families import hash_codes_np
 from repro.core.params import PlanConfig
 from repro.core.wlsh import WLSHIndex
 
@@ -77,6 +78,74 @@ def test_faithful_vs_dense_same_stop_semantics(built):
                 )
 
 
+def _dense_formula(idx, q, weight_id, k, c=None):
+    """search_dense's semantics written out densely: per (point, table)
+    first-collision level, the mu-th order statistic, then the stop
+    conditions over every point — (ids, dists, stop, n_checked,
+    n_collisions, found_k)."""
+    built, slot, beta_i, mu_i = idx._member_params(weight_id)
+    r_min = float(built.plan.r_min_members[slot])
+    n_levels = int(built.plan.n_levels[slot])
+    c = idx._c_eff(idx.cfg.c, c)
+    budget = k + int(np.ceil(idx.cfg.gamma_n))
+    q = np.asarray(q, np.float32)
+    b = hash_codes_np(q[None, :], built.fam)[0][:beta_i].astype(np.int64)
+    a = built.codes[:, :beta_i].astype(np.int64)
+    jmin = np.full(a.shape, n_levels + 1, np.int16)
+    for j in range(n_levels + 1):
+        jmin[(a == b[None, :]) & (jmin > n_levels)] = j
+        a //= c
+        b //= c
+    if mu_i > beta_i:
+        l_freq = np.full(idx.n, n_levels + 1, np.int16)
+    else:
+        l_freq = np.partition(jmin, mu_i - 1, axis=1)[:, mu_i - 1]
+    dists = weighted_lp_np(idx.data, q, idx.weights[weight_id], idx.cfg.p)
+    stop, n_checked, found_k = n_levels, 0, False
+    for j in range(n_levels + 1):
+        freq = l_freq <= j
+        n_chk = min(int(freq.sum()), budget)
+        n_good = int(np.sum(freq & (dists <= c * r_min * c**j)))
+        if n_good >= k or n_chk >= budget:
+            stop, n_checked, found_k = j, n_chk, n_good >= k
+            break
+        n_checked = n_chk
+    cand = np.where(l_freq <= stop)[0]
+    top = cand[np.argsort(dists[cand], kind="stable")[:k]]
+    ids = np.full(k, -1, np.int64)
+    out_d = np.full(k, np.inf)
+    ids[: top.size] = top
+    out_d[: top.size] = dists[top]
+    return ids, out_d, stop, n_checked, int(np.sum(jmin <= stop)), found_k
+
+
+@pytest.mark.parametrize("p,tau", [(2.0, 500.0), (1.0, 1_000.0),
+                                   (0.5, 2_000.0)])
+@pytest.mark.parametrize("c", [None, 5])
+def test_search_dense_matches_dense_formula(p, tau, c):
+    """The collision-driven search_dense equals the dense formula it
+    implements, field for field, including the query-time c override."""
+    data = make_dataset(n=3_000, d=16, seed=21)
+    weights = make_weight_set(size=6, d=16, n_subset=2, n_subrange=10,
+                              seed=22)
+    idx = WLSHIndex(data, weights, PlanConfig(p=p, c=3, n=len(data),
+                                              gamma_n=100.0),
+                    tau=tau, v=4, v_prime=4, seed=23)
+    rng = np.random.default_rng(24)
+    for qi in range(32):
+        q = data[rng.integers(len(data))] + rng.normal(0, 30.0, 16)
+        wid = int(rng.integers(len(weights)))
+        k = (1, 5, 10)[qi % 3]
+        got = idx.search_dense(q, weight_id=wid, k=k, c=c)
+        ids, dists, stop, n_checked, n_coll, found_k = _dense_formula(
+            idx, q, wid, k, c)
+        np.testing.assert_array_equal(got.ids, ids)
+        np.testing.assert_array_equal(got.dists, dists)
+        assert (got.stats.stop_level, got.stats.n_checked,
+                got.stats.n_collisions, got.stats.found_k) == (
+            stop, n_checked, n_coll, found_k)
+
+
 def test_self_query_finds_itself(built):
     """A query that IS a data point must return it at distance ~0."""
     idx, _ = built
@@ -97,7 +166,7 @@ def test_io_accounting(built):
 
 def test_c2lsh_degeneration():
     """WLSH with |S| = 1 is exactly C2LSH (shared plumbing, Eqs. 4-5)."""
-    data = make_dataset(n=1_500, d=16, seed=21)
+    data = make_dataset(n=3_000, d=16, seed=21)
     w = np.ones(16)
     cfg = PlanConfig(p=2.0, c=3, n=len(data), gamma_n=100.0)
     c2 = C2LSH(data, cfg, weight=w, seed=5)
