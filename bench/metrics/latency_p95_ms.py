@@ -1,0 +1,11 @@
+"""95th percentile latency of every request due in the window, ms.
+
+From each request's due time to the resolution of its future (host
+clock); an unanswered request counts as infinitely late.
+"""
+
+from bench.harness import percentile
+
+
+def read(run):
+    return percentile(run.window.latency_ms, 95)
