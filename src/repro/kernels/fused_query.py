@@ -247,6 +247,7 @@ def fused_query_hist_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
+        name="fused_query_hist",
     )(
         _per_query_rows(codes_q, jnp.int32),
         codes_p.astype(jnp.int32),
@@ -304,6 +305,7 @@ def fused_query_scores_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
+        name="fused_query_scores",
     )(
         _per_query_rows(codes_q, jnp.int32),
         codes_p.astype(jnp.int32),

@@ -11,11 +11,27 @@ signature per (shape bucket, rung, shard count, kernel path).  The
   launch, per signature.
 
 Both are host-side bookkeeping and never touch device values, so
-enabling them is bit-exact.  The dispatch scope also opens a
-``jax.profiler.TraceAnnotation`` region, so launches are labeled in a
-captured device trace, and ``start_trace`` / ``stop_trace`` bracket an
-on-demand ``jax.profiler`` capture.  A capture that fails to start or
-stop raises: a requested trace is never silently missing.
+enabling them is bit-exact.  :meth:`Profiler.span` opens a
+``jax.profiler.TraceAnnotation`` region ``name[detail]`` on the
+profiler's clock, which is the device trace's; the dispatch scope is
+its one timed use.  The serving stack's spans, all ``wlsh_*``:
+
+* ``wlsh_wait_idle`` / ``wlsh_wait_deadline`` — the driver thread
+  asleep with nothing pending / until a pending request's deadline;
+* ``wlsh_lease`` — the launch's ``StateCache`` acquire (hit, restore
+  or build of the group's state);
+* ``wlsh_encode`` — the host query codes;
+* ``wlsh_query_step[sig]`` — the timed dispatch: input copies, the
+  compiled step, the readback;
+* ``wlsh_readback`` — inside it, the wait for the device and the
+  outputs' copy back;
+* ``wlsh_resolve`` — finishing a launch: rung padding, the delta
+  merge, counters, span stamps, resolving the futures.
+
+Arguments (group, rows, signature) go only in the ``[...]`` suffix, so
+a trace has one label per span kind.  ``start_trace`` / ``stop_trace``
+bracket an on-demand ``jax.profiler`` capture.  A capture that fails to
+start or stop raises: a requested trace is never silently missing.
 """
 
 from __future__ import annotations
@@ -52,12 +68,18 @@ class Profiler:
         with self._lock:
             self._compiles[sig] = self._compiles.get(sig, 0) + 1
 
+    @staticmethod
+    def span(name: str, detail=""):
+        """An untimed region ``name[detail]`` in captured device traces."""
+        return jax.profiler.TraceAnnotation(
+            name if detail == "" else f"{name}[{detail}]")
+
     @contextlib.contextmanager
     def dispatch(self, sig: str):
         """Time one compiled-step launch, annotated in device traces."""
         t0 = self._timer()
         try:
-            with jax.profiler.TraceAnnotation(f"wlsh_query_step[{sig}]"):
+            with self.span("wlsh_query_step", sig):
                 yield
         finally:
             dt = self._timer() - t0

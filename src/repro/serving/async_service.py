@@ -478,31 +478,32 @@ class AsyncRetrievalService:
             # has lost nothing and no future is stranded unresolvable
             q.extendleft(reversed(batch))
             raise
-        if cause == "full":
-            self.n_launched_full += 1
-        elif cause == "deadline":
-            self.n_launched_deadline += 1
-        else:
-            self.n_launched_drain += 1
-        now = self.clock()
-        wait_h = self.batcher.metrics.histogram(
-            "wlsh_query_wait_seconds",
-            "submit-to-resolve wait on the service clock",
-        )
-        for i, r in enumerate(batch):  # submission order within the launch
-            r.future._resolve(QueryAnswer(
-                ids=ids[i], dists=dists[i], group_id=gi,
-                stop_level=int(stop[i]), n_checked=int(chk[i]),
-            ), now)
-            wait_h.observe(now - r.t_submit)
-            if r.span is not None:
-                r.span.cause = cause
-                r.span.mark("resolve", now)
-                tr.finish(r.span)
-            if self.qos is not None:
-                self.qos.on_resolved(
-                    r.tenant, now - r.t_submit, now > r.deadline, rung
-                )
+        with self.batcher.span("wlsh_resolve", len(batch)):
+            if cause == "full":
+                self.n_launched_full += 1
+            elif cause == "deadline":
+                self.n_launched_deadline += 1
+            else:
+                self.n_launched_drain += 1
+            now = self.clock()
+            wait_h = self.batcher.metrics.histogram(
+                "wlsh_query_wait_seconds",
+                "submit-to-resolve wait on the service clock",
+            )
+            for i, r in enumerate(batch):  # submission order within the launch
+                r.future._resolve(QueryAnswer(
+                    ids=ids[i], dists=dists[i], group_id=gi,
+                    stop_level=int(stop[i]), n_checked=int(chk[i]),
+                ), now)
+                wait_h.observe(now - r.t_submit)
+                if r.span is not None:
+                    r.span.cause = cause
+                    r.span.mark("resolve", now)
+                    tr.finish(r.span)
+                if self.qos is not None:
+                    self.qos.on_resolved(
+                        r.tenant, now - r.t_submit, now > r.deadline, rung
+                    )
 
 
 def _replay(svc: AsyncRetrievalService, queries, weight_ids, arrivals,

@@ -52,7 +52,7 @@ from ..index.engine import QueryStepCache, encode_queries
 from .qos import DegradeStep
 from .state_cache import StateCache
 
-_NULL_SCOPE = contextlib.nullcontext()  # profiler-off dispatch scope
+_NULL_SCOPE = contextlib.nullcontext()  # profiler-off span scope
 
 __all__ = [
     "BatchPlan",
@@ -661,6 +661,16 @@ class Batcher:
             base_rows=base_rows,
         )
 
+    def span(self, name: str, detail=""):
+        """Profiler region ``name[detail]`` around a serving stage.
+
+        With ``cfg.obs`` off this is the shared null scope: no
+        annotation is built and ``detail`` is never formatted.
+        """
+        if self.profiler is None:
+            return _NULL_SCOPE
+        return self.profiler.span(name, detail)
+
     def _note_cache_event(self, gi: int, kind: str) -> None:
         """Record a StateCache event for trace-span stage attribution.
 
@@ -871,7 +881,10 @@ class Batcher:
                 spans.append(s)
         if tr is not None:
             self._cache_events = []
-        with self.state_cache.lease(gi) as state:
+        span = self.span
+        with span("wlsh_lease", gi):
+            state = self.state_cache.acquire(gi)
+        try:
             if tr is not None and spans:
                 # attribute this launch's paging work: a consumed
                 # prefetch marks "prefetch", a blocking restore/build
@@ -883,9 +896,10 @@ class Batcher:
                         s.mark("prefetch", t_acq)
                     if kinds & {"restore", "build"}:
                         s.mark("restore", t_acq)
-            codes = self._encode(
-                gi, cfg, state, queries, take
-            ).astype(np.int32)
+            with span("wlsh_encode", real):
+                codes = self._encode(
+                    gi, cfg, state, queries, take
+                ).astype(np.int32)
             if tr is not None and spans:
                 t_launch = self.clock()
                 for s in spans:
@@ -911,63 +925,67 @@ class Batcher:
                 )
                 # materialize before releasing the lease: the state must
                 # stay resident until the device has finished reading it
-                ids = np.asarray(i_b)[:real]
-                dists = np.asarray(d_b)[:real]
-                stop = np.asarray(stop_b)[:real]
-                chk = np.asarray(chk_b)[:real]
-        if cfg.k < self.cfg.k:
-            # degraded rung: pad the short top-k back to the strict width
-            # (missing-slot conventions, so downstream merge/augment and
-            # every result consumer see one uniform shape)
-            pad_ids = np.full((real, self.cfg.k), -1, ids.dtype)
-            pad_d = np.full((real, self.cfg.k), np.inf, dists.dtype)
-            pad_ids[:, : cfg.k] = ids
-            pad_d[:, : cfg.k] = dists
-            ids, dists = pad_ids, pad_d
-        if self._delta is not None:
-            # translate appended state rows to global ids, merge the exact
-            # delta-scan hits, filter tombstones (no-op passthrough for a
-            # group with nothing pending — the parity guarantee)
-            ids, dists = self._delta.augment(
-                gi, queries, weight_ids, ids, dists
-            )
-        m = self.metrics
-        m.counter("wlsh_group_batches_total",
-                  "compiled-step launches").inc(group=gi)
-        m.counter("wlsh_group_queries_total",
-                  "real rows served").inc(real, group=gi)
-        m.counter("wlsh_group_padded_rows_total",
-                  "padding rows across ragged batches").inc(
-            cfg.q_batch - real, group=gi)
-        m.counter("wlsh_group_stop_levels_total",
-                  "summed histogram stop levels").inc(
-            int(np.sum(stop)), group=gi)
-        m.counter("wlsh_group_checked_total",
-                  "summed candidates verified (n_checked)").inc(
-            int(np.sum(chk)), group=gi)
-        if tr is not None and spans:
-            self._cache_events = None
-            t_merge = self.clock()
-            budget = int(cfg.budget)
-            for i, s in enumerate(spans):
-                s.mark("merge", t_merge)
-                s.group_id = int(gi)
-                s.rung = int(rung)
-                s.n_shards = int(self.mesh.size)
-                s.stop_level = int(stop[i])
-                s.n_checked = int(chk[i])
-                s.budget = budget
-                s.budget_capped = bool(int(chk[i]) >= budget)
-                if own_spans:
-                    s.mark("resolve", t_merge)
-                    tr.finish(s)
-            if self.recall is not None:
-                # shadow-sample by deterministic hash of the span's query
-                # id: enqueue only (host copies) — the answer arrays are
-                # returned untouched, so sampling is bit-invisible
+                with span("wlsh_readback"):
+                    ids = np.asarray(i_b)[:real]
+                    dists = np.asarray(d_b)[:real]
+                    stop = np.asarray(stop_b)[:real]
+                    chk = np.asarray(chk_b)[:real]
+        finally:
+            self.state_cache.release(gi)
+        with span("wlsh_resolve", real):
+            if cfg.k < self.cfg.k:
+                # degraded rung: pad the short top-k back to the strict width
+                # (missing-slot conventions, so downstream merge/augment and
+                # every result consumer see one uniform shape)
+                pad_ids = np.full((real, self.cfg.k), -1, ids.dtype)
+                pad_d = np.full((real, self.cfg.k), np.inf, dists.dtype)
+                pad_ids[:, : cfg.k] = ids
+                pad_d[:, : cfg.k] = dists
+                ids, dists = pad_ids, pad_d
+            if self._delta is not None:
+                # translate appended state rows to global ids, merge the exact
+                # delta-scan hits, filter tombstones (no-op passthrough for a
+                # group with nothing pending — the parity guarantee)
+                ids, dists = self._delta.augment(
+                    gi, queries, weight_ids, ids, dists
+                )
+            m = self.metrics
+            m.counter("wlsh_group_batches_total",
+                      "compiled-step launches").inc(group=gi)
+            m.counter("wlsh_group_queries_total",
+                      "real rows served").inc(real, group=gi)
+            m.counter("wlsh_group_padded_rows_total",
+                      "padding rows across ragged batches").inc(
+                cfg.q_batch - real, group=gi)
+            m.counter("wlsh_group_stop_levels_total",
+                      "summed histogram stop levels").inc(
+                int(np.sum(stop)), group=gi)
+            m.counter("wlsh_group_checked_total",
+                      "summed candidates verified (n_checked)").inc(
+                int(np.sum(chk)), group=gi)
+            if tr is not None and spans:
+                self._cache_events = None
+                t_merge = self.clock()
+                budget = int(cfg.budget)
                 for i, s in enumerate(spans):
-                    self.recall.offer(
-                        s, queries[i], int(weight_ids[i]), int(gi),
-                        int(rung), ids[i]
-                    )
-        return ids, dists, stop, chk
+                    s.mark("merge", t_merge)
+                    s.group_id = int(gi)
+                    s.rung = int(rung)
+                    s.n_shards = int(self.mesh.size)
+                    s.stop_level = int(stop[i])
+                    s.n_checked = int(chk[i])
+                    s.budget = budget
+                    s.budget_capped = bool(int(chk[i]) >= budget)
+                    if own_spans:
+                        s.mark("resolve", t_merge)
+                        tr.finish(s)
+                if self.recall is not None:
+                    # shadow-sample by deterministic hash of the span's query
+                    # id: enqueue only (host copies) — the answer arrays are
+                    # returned untouched, so sampling is bit-invisible
+                    for i, s in enumerate(spans):
+                        self.recall.offer(
+                            s, queries[i], int(weight_ids[i]), int(gi),
+                            int(rung), ids[i]
+                        )
+            return ids, dists, stop, chk

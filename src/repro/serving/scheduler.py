@@ -560,6 +560,7 @@ class ServiceDriver:
             self.svc.driver = None
 
     def _run(self) -> None:
+        span = self.svc.batcher.span
         while not self._stop.is_set():
             self.step()
             with self._lock:
@@ -569,7 +570,11 @@ class ServiceDriver:
                 min(max(nd - now, 0.0), self.tick_s)
             )
             if wait > 0:
-                self._wake.wait(wait)
+                # nothing pending (no deadline) is headroom; a pending
+                # request's deadline is a batching delay it pays
+                with span("wlsh_wait_idle" if nd is None
+                          else "wlsh_wait_deadline"):
+                    self._wake.wait(wait)
             self._wake.clear()
 
 

@@ -98,3 +98,32 @@ def test_query_step_compiles_with_the_pallas_kernels(topo, monkeypatch):
     step = make_query_step(mesh, cfg)
     hlo = step.lower(*query_input_specs(cfg).values()).compile().as_text()
     assert hlo.count("tpu_custom_call") >= 2  # pass 1 and pass 2
+
+
+@pytest.mark.parametrize("kind", ["hist", "scores"])
+def test_fused_kernel_custom_call_carries_its_name(one_chip, kind):
+    """Each pass's Mosaic call is named for the kernel (``pallas_call``'s
+    ``name=``), which is the op name a device trace lists."""
+    beta, d, rows = 128, 128, 2 * BN
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(codes_p, points, codes_q, queries, q_weight, mu, r_min, beta_q,
+             boff, n_valid, stop):
+        return ops.fused_query_block(
+            codes_p, points, codes_q, queries, q_weight, mu, r_min, beta_q,
+            boff=boff, n_valid=n_valid, c=3, n_levels=16, p=2.0,
+            stop=stop if kind == "scores" else None,
+            use_pallas=True, interpret=False, bn=BN)
+
+    hlo = jax.jit(step).lower(
+        spec((rows, beta), jnp.int32), spec((rows, d), jnp.float32),
+        spec((Q, beta), jnp.int32), spec((Q, d), jnp.float32),
+        spec((Q, d), jnp.float32), spec((Q,), jnp.int32),
+        spec((Q,), jnp.float32), spec((Q,), jnp.int32),
+        spec((), jnp.int32), spec((), jnp.int32), spec((Q,), jnp.int32),
+    ).compile().as_text()
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert calls and all(
+        ln.lstrip().startswith(f"%fused_query_{kind}.") for ln in calls)
