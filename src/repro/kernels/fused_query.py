@@ -14,6 +14,15 @@ bound by.  Two modes, one per engine pass:
                    l_p distances masked by the query's stop level, ready
                    for the engine's running top-k.
 
+Both passes find the first-frequent level the same way: for each level j
+the query row's codes are floor-divided by c once more, turned into the
+bucket bounds [q_j c^j, (q_j + 1) c^j - 1] (clipped to int32, empty past
+the member's beta_q), and the point tile's codes are counted inside them
+with two compares.  That is exactly the oracle's floor(a / c^j) ==
+floor(b / c^j) test, with every integer division on the (1, beta) query
+row and none on the (BN, beta) point tile, where the TPU would expand it
+into long multiply/shift sequences per vreg.
+
 Grid: (Q, block/BN).  Query code row (1, beta) and point codes (BN, beta)
 stay whole in the lane axis, as do the (1, d)/(BN, d) vector tiles.
 Per-query operands and outputs are passed as (Q, 1, X) with the query
@@ -50,6 +59,9 @@ def nbins(n_levels: int) -> int:
     return 128 * math.ceil((n_levels + 3) / 128)
 
 
+_I32_MIN, _I32_MAX = -(2**31), 2**31 - 1
+
+
 def _floor_div(x, c: int):
     # lax integer div truncates toward zero; emulate floor for negatives.
     q = jax.lax.div(x, jnp.int32(c))
@@ -58,27 +70,47 @@ def _floor_div(x, c: int):
     return q - jnp.where(neg, 1, 0).astype(jnp.int32)
 
 
+def _bucket_bounds(q, cj: int):
+    """int32 codes a with floor(a / cj) == q, as inclusive [lo, hi].
+
+    ``q`` is the query's level-j bucket floor(b / cj) and ``cj`` = c**j a
+    Python int.  The bucket [q*cj, (q+1)*cj - 1] is clipped to the int32
+    range, where every code lies, so neither end overflows.
+    """
+    if cj > _I32_MAX:  # q is -1 or 0: every negative or every other code
+        neg = q < 0
+        return (jnp.where(neg, _I32_MIN, 0).astype(jnp.int32),
+                jnp.where(neg, -1, _I32_MAX).astype(jnp.int32))
+    q_lo = -((2**31) // cj)  # least q whose bucket starts in range
+    q_hi = (2**31 - cj) // cj  # greatest q whose bucket ends in range
+    lo = jnp.where(q >= q_lo, jnp.maximum(q, q_lo) * cj, _I32_MIN)
+    hi = jnp.where(q <= q_hi, jnp.minimum(q, q_hi) * cj + (cj - 1), _I32_MAX)
+    return lo, hi
+
+
 def _lf_and_dist(cq_ref, cp_ref, qpt_ref, ppt_ref, w_ref, mu_ref, bq_ref,
                  *, c: int, n_levels: int, p: float):
-    """(1, BN) first-frequent level + (1, BN) weighted l_p distance."""
+    """(1, BN) first-frequent level + (1, BN) weighted l_p distance.
+
+    A point collides with the query at level j when floor(a / c**j) ==
+    floor(b / c**j), i.e. when a lies in the query's level-j bucket.  The
+    bucket bounds come from the (1, beta) query row, so the (BN, beta)
+    point tile is only compared, never divided.  Lanes at or past the
+    member's ``beta_q`` get the empty bucket [1, 0].
+    """
     a = cp_ref[...].astype(jnp.int32)  # (BN, beta)
-    b = cq_ref[...].astype(jnp.int32)  # (1, beta)
+    q = cq_ref[...].astype(jnp.int32)  # (1, beta)
     mu = mu_ref[0, 0]
-    beta_q = bq_ref[0, 0]
-    lane = jax.lax.broadcasted_iota(jnp.int32, a.shape, 1)
-    lane_ok = (lane < beta_q).astype(jnp.int32)
+    lane_ok = jax.lax.broadcasted_iota(jnp.int32, q.shape, 1) < bq_ref[0, 0]
     never = jnp.int32(n_levels + 1)
-    out = jnp.full((1, a.shape[0]), never, jnp.int32)
-
-    def body(j, carry):
-        a, b, out = carry
-        cnt = jnp.sum((a == b).astype(jnp.int32) * lane_ok, axis=1)[None, :]
-        out = jnp.where((cnt >= mu) & (out == never), jnp.int32(j), out)
-        return (_floor_div(a, c), _floor_div(b, c), out)
-
-    _, _, lf = jax.lax.fori_loop(
-        0, n_levels + 1, body, (a, b, out), unroll=True
-    )
+    lf = jnp.full((1, a.shape[0]), never, jnp.int32)
+    for j in range(n_levels + 1):
+        lo, hi = _bucket_bounds(q, c**j)
+        lo = jnp.where(lane_ok, lo, 1)
+        hi = jnp.where(lane_ok, hi, 0)
+        cnt = jnp.sum(((a >= lo) & (a <= hi)).astype(jnp.int32), axis=1)
+        lf = jnp.where((cnt[None, :] >= mu) & (lf == never), jnp.int32(j), lf)
+        q = _floor_div(q, c)
 
     x = ppt_ref[...]  # (BN, d)
     qv = qpt_ref[...]  # (1, d)

@@ -20,6 +20,8 @@ executed), matching how the kernels are validated off-TPU.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -356,6 +358,125 @@ def test_fused_scalar_broadcast_and_default_beta():
                               use_pallas=False, **kw)
     np.testing.assert_array_equal(np.array(a[0]), np.array(b[0]))
     np.testing.assert_array_equal(np.array(a[1]), np.array(b[1]))
+
+
+_I32 = np.iinfo(np.int32)
+
+
+def _mk_edge_codes(c, L, n, beta, Q, rng):
+    """Query and point codes that collide at every level 0..L+2, with codes
+    at the int32 extremes and on both sides of negative bucket edges.
+
+    Row r is built from query r % Q: each lane lies within c**t of that
+    query's code for a random t, so first-frequent levels spread out.
+    """
+    cq = rng.integers(_I32.min, _I32.max, (Q, beta), endpoint=True,
+                      dtype=np.int64)
+    extremes = [_I32.min, _I32.min + 1, -(2**31 - 1) + 5, _I32.max,
+                _I32.max - 1, -1, 0]
+    cq[:, : len(extremes)] = extremes
+    edge_lanes = np.arange(len(extremes), len(extremes) + beta // 4)
+    j = rng.integers(1, L + 1, (Q, edge_lanes.size))
+    cj = np.minimum(float(c) ** j, 2.0**31).astype(np.int64)
+    cq[:, edge_lanes] = -rng.integers(1, 50, (Q, edge_lanes.size)) * cj
+    cq = np.clip(cq, _I32.min, _I32.max)
+
+    ref_q = cq[np.arange(n) % Q]  # (n, beta)
+    t = rng.integers(0, L + 3, (n, beta))
+    span = np.minimum(float(c) ** t, 2.0**33).astype(np.int64)
+    cp = ref_q + rng.integers(-span, span, endpoint=True)
+    # just inside and just outside the query's negative level-j bucket
+    cj_r = cj[np.arange(n) % Q]
+    step = rng.choice([-1, 0, 1, 2], (n, edge_lanes.size))
+    cp[:, edge_lanes] = np.where(
+        step == -1, ref_q[:, edge_lanes] - 1,
+        ref_q[:, edge_lanes] + np.where(step == 2, cj_r, step * (cj_r - 1)))
+    cp[n // 3:: 7] = _I32.min  # rows at the extremes
+    cp[n // 3 + 1:: 7] = _I32.max
+    cp = np.clip(cp, _I32.min, _I32.max)
+    return cp.astype(np.int32), cq.astype(np.int32)
+
+
+@pytest.mark.parametrize("mode", ["all_tables", "narrow_member_streaming"])
+@pytest.mark.parametrize("c,n_levels", [(2, 14), (2, 18), (2, 24), (2, 33),
+                                        (3, 14), (3, 18), (3, 24)])
+def test_fused_levels_exact_at_int32_edges(c, n_levels, mode):
+    """Both fused passes equal the ref.py oracle bit for bit where bucket
+    bounds meet the int32 range: c**j past 2**31, codes at +-(2**31 - 1)
+    and -2**31, negative codes one step either side of a bucket edge,
+    members narrower than the state (beta_q < beta) and a streaming
+    watermark below the block's rows."""
+    n, d, beta, Q = 192, 128, 160, 4
+    rng = np.random.default_rng(1000 * c + n_levels)
+    cp, cq = _mk_edge_codes(c, n_levels, n, beta, Q, rng)
+    pts = rng.uniform(0, 1000, (n, d)).astype(np.float32)
+    qs = rng.uniform(0, 1000, (Q, d)).astype(np.float32)
+    qw = rng.uniform(1, 10, (Q, d)).astype(np.float32)
+    mu = rng.integers(beta // 8, beta // 2, Q).astype(np.int32)
+    r_min = rng.uniform(10.0, 200.0, Q).astype(np.float32)
+    stop = rng.integers(0, n_levels + 1, Q).astype(np.int32)
+    if mode == "all_tables":
+        beta_q, boff, n_valid = np.full(Q, beta, np.int32), 0, n
+    else:
+        beta_q = rng.integers(beta // 2, beta, Q).astype(np.int32)
+        boff, n_valid = 500, 500 + n - 37
+    # p = 1 at a lane-multiple d: the distances are bit-exact too
+    kw = dict(boff=boff, n_valid=n_valid, c=c, n_levels=n_levels, p=1.0)
+    args = (cp, pts, cq, qs, qw, mu, r_min, beta_q)
+    hf0, hg0 = ops.fused_query_block(*args, use_pallas=False, **kw)
+    hf1, hg1 = ops.fused_query_block(*args, use_pallas=True, interpret=True,
+                                     bn=128, **kw)
+    np.testing.assert_array_equal(np.array(hf0), np.array(hf1))
+    np.testing.assert_array_equal(np.array(hg0), np.array(hg1))
+    # the data reach several levels, not only "never frequent"
+    assert (np.array(hf0)[:, : n_levels + 1].sum(axis=0) > 0).sum() >= 3
+    s0 = ops.fused_query_block(*args, stop=stop, use_pallas=False, **kw)
+    s1 = ops.fused_query_block(*args, stop=stop, use_pallas=True,
+                               interpret=True, bn=128, **kw)
+    np.testing.assert_array_equal(np.array(s0), np.array(s1))
+
+
+def _eqns(jaxpr):
+    """Every eqn of a jaxpr and of the jaxprs nested in its eqns."""
+    from jax.extend import core as jcore
+
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, jcore.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jcore.Jaxpr):
+                    yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("kind", ["hist", "scores"])
+def test_fused_kernel_divides_no_point_tile(kind):
+    """No integer division or remainder inside the fused kernel body acts
+    on the (bn, beta) point-code tile: the level test divides only the
+    (1, beta) query row."""
+    from repro.kernels import fused_query as fq
+
+    rows, bn, beta, d, Q, L = 512, 256, 480, 128, 8, 14
+    fn = {"hist": fq.fused_query_hist_pallas,
+          "scores": fq.fused_query_scores_pallas}[kind]
+    per_q = jnp.zeros((Q,), jnp.int32)
+    r_min_or_stop = jnp.zeros((Q,), jnp.float32 if kind == "hist" else
+                              jnp.int32)
+    jaxpr = jax.make_jaxpr(functools.partial(
+        fn, c=3, n_levels=L, p=2.0, n_rows=rows, bn=bn))(
+        jnp.zeros((rows, beta), jnp.int32), jnp.zeros((rows, d)),
+        jnp.zeros((Q, beta), jnp.int32), jnp.zeros((Q, d)),
+        jnp.zeros((Q, d)), per_q, per_q, r_min_or_stop, jnp.int32(0),
+        jnp.int32(0))
+    calls = [e for e in _eqns(jaxpr.jaxpr)
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    divs = [e for e in _eqns(calls[0].params["jaxpr"])
+            if e.primitive.name in ("div", "rem")]
+    shapes = {tuple(v.aval.shape) for e in divs for v in e.outvars}
+    assert (1, beta) in shapes  # the query row's bucket chain is seen
+    assert (bn, beta) not in shapes
 
 
 def test_hash_encode_matches_host_family():

@@ -56,12 +56,8 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("kind", ["hist", "scores"])
-@pytest.mark.parametrize("d,p", [(128, 2.0), (960, 0.5)])
-def test_fused_kernel_compiles_at_service_batch(one_chip, kind, d, p):
-    """Both fused passes, through the ops wrapper, at Q = 8 and beta = 512
-    (per-query (1, X) blocks of a (Q, X) array were refused for Q > 1)."""
-    beta = 512
+def _compile_fused(one_chip, kind, beta, d, p, n_levels, rows=ROWS):
+    """Compiled HLO text of one fused pass through the ops wrapper."""
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -70,18 +66,38 @@ def test_fused_kernel_compiles_at_service_batch(one_chip, kind, d, p):
              boff, n_valid, stop):
         return ops.fused_query_block(
             codes_p, points, codes_q, queries, q_weight, mu, r_min, beta_q,
-            boff=boff, n_valid=n_valid, c=3, n_levels=16, p=p,
+            boff=boff, n_valid=n_valid, c=3, n_levels=n_levels, p=p,
             stop=stop if kind == "scores" else None,
             use_pallas=True, interpret=False, bn=BN)
 
-    compiled = jax.jit(step).lower(
-        spec((ROWS, beta), jnp.int32), spec((ROWS, d), jnp.float32),
+    return jax.jit(step).lower(
+        spec((rows, beta), jnp.int32), spec((rows, d), jnp.float32),
         spec((Q, beta), jnp.int32), spec((Q, d), jnp.float32),
         spec((Q, d), jnp.float32), spec((Q,), jnp.int32),
         spec((Q,), jnp.float32), spec((Q,), jnp.int32),
         spec((), jnp.int32), spec((), jnp.int32), spec((Q,), jnp.int32),
-    ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    ).compile().as_text()
+
+
+@pytest.mark.parametrize("kind", ["hist", "scores"])
+@pytest.mark.parametrize("d,p", [(128, 2.0), (960, 0.5)])
+def test_fused_kernel_compiles_at_service_batch(one_chip, kind, d, p):
+    """Both fused passes, through the ops wrapper, at Q = 8 and beta = 512
+    (per-query (1, X) blocks of a (Q, X) array were refused for Q > 1)."""
+    assert "tpu_custom_call" in _compile_fused(one_chip, kind, 512, d, p, 16)
+
+
+@pytest.mark.parametrize("kind", ["hist", "scores"])
+@pytest.mark.parametrize("beta,d,p,n_levels", [
+    (480, 128, 2.0, 14),  # sift128_p2: group 0's padded state
+    (416, 960, 1.0, 18),  # gist960_p1
+], ids=["sift128_p2", "gist960_p1"])
+def test_fused_kernel_compiles_at_cell_shapes(one_chip, kind, beta, d, p,
+                                              n_levels):
+    """Both fused passes at the benchmark cells' state widths, distance
+    and level count, at Q = 8 and bn = 256."""
+    hlo = _compile_fused(one_chip, kind, beta, d, p, n_levels)
+    assert "tpu_custom_call" in hlo
 
 
 def test_query_step_compiles_with_the_pallas_kernels(topo, monkeypatch):
@@ -104,26 +120,7 @@ def test_query_step_compiles_with_the_pallas_kernels(topo, monkeypatch):
 def test_fused_kernel_custom_call_carries_its_name(one_chip, kind):
     """Each pass's Mosaic call is named for the kernel (``pallas_call``'s
     ``name=``), which is the op name a device trace lists."""
-    beta, d, rows = 128, 128, 2 * BN
-
-    def spec(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    def step(codes_p, points, codes_q, queries, q_weight, mu, r_min, beta_q,
-             boff, n_valid, stop):
-        return ops.fused_query_block(
-            codes_p, points, codes_q, queries, q_weight, mu, r_min, beta_q,
-            boff=boff, n_valid=n_valid, c=3, n_levels=16, p=2.0,
-            stop=stop if kind == "scores" else None,
-            use_pallas=True, interpret=False, bn=BN)
-
-    hlo = jax.jit(step).lower(
-        spec((rows, beta), jnp.int32), spec((rows, d), jnp.float32),
-        spec((Q, beta), jnp.int32), spec((Q, d), jnp.float32),
-        spec((Q, d), jnp.float32), spec((Q,), jnp.int32),
-        spec((Q,), jnp.float32), spec((Q,), jnp.int32),
-        spec((), jnp.int32), spec((), jnp.int32), spec((Q,), jnp.int32),
-    ).compile().as_text()
+    hlo = _compile_fused(one_chip, kind, 128, 128, 2.0, 16, rows=2 * BN)
     calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
     assert calls and all(
         ln.lstrip().startswith(f"%fused_query_{kind}.") for ln in calls)
