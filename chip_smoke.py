@@ -50,11 +50,14 @@ _SHAPE = ["--d", "128", "--k", "10", "--n-weights", "8", "--n-subset", "2",
           "--async", "--driver", "--check"]
 _TAU = {2.0: "500", 1.0: "1000", 0.5: "2000"}
 # rows of the main phase, cut from SIFT-1M's 1,000,000: the host planner
-# keeps about 21 bytes per row per table (the n x beta codes, their
-# argsort and sorted copies) beside the TPU runtime's own host memory.
-# On a one-chip v5e host (40 GiB) the run peaked at 25.25 GiB for
-# 500,000 rows and 32.21 GiB for 750,000; the full size extrapolates to
-# about 39 GiB, too close to the host's limit
+# keeps 4 bytes per row per table (the n x beta codes) and hashes one
+# group at a time in float64; ``--check``'s first search of a group adds
+# its sorted copies, 8 bytes per row per table, and about 12 more of
+# argsort temporaries while it sorts, beside the TPU runtime's own host
+# memory. On a one-chip v5e host (40 GiB) the run peaked at 25.25 GiB
+# for 500,000 rows and 32.21 GiB for 750,000 while every group's tables
+# were still built up front; the full size extrapolated to about 39 GiB,
+# too close to the host's limit
 N_MAIN = 750_000
 # rows of the p = 1 / p = 0.5 phases, the streaming phase and the
 # four-chip comparison: the host oracle's time per query, not the chip,

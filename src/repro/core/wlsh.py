@@ -1,10 +1,12 @@
 """WLSH index: Preprocess (Algorithm 1) + Search (Algorithm 2).
 
 This module is the *paper-faithful* host implementation (numpy): hash tables
-are per-function sorted code arrays; the search runs the C2LSH virtual-
-rehashing level loop with incremental collision counting, so its work (and
-the I/O metric we report) is proportional to the buckets actually probed —
-exactly the quantity the paper's experiments measure.
+are per-function sorted code arrays, built on the first host search of a
+group (the serving-plan export ships only the raw codes and never builds
+them); the search runs the C2LSH virtual-rehashing level loop with
+incremental collision counting, so its work (and the I/O metric we report)
+is proportional to the buckets actually probed — exactly the quantity the
+paper's experiments measure.
 
 The TPU-dense formulation (single-pass L_freq order statistic, Pallas
 kernels, sharded execution) lives in ``repro.index`` / ``repro.kernels`` and
@@ -59,9 +61,20 @@ class SearchResult:
 class BuiltGroup:
     plan: GroupPlan
     fam: LpFamilyParams
-    sorted_codes: np.ndarray  # (beta, n) int32, per-table ascending codes
-    sorted_ids: np.ndarray  # (beta, n) int32, matching point ids
     codes: np.ndarray  # (n, beta) int32 raw codes (dense path / export)
+    # (sorted_codes, sorted_ids), each (beta, n) int32: per-table ascending
+    # codes and their point ids, built on the group's first host search
+    # (``sorted_tables``); None until then
+    tables: tuple[np.ndarray, np.ndarray] | None = None
+
+    def sorted_tables(self) -> tuple[np.ndarray, np.ndarray]:
+        """The group's sorted hash tables, built once and kept."""
+        if self.tables is None:
+            order = np.argsort(self.codes, axis=0, kind="stable")  # (n, beta)
+            sorted_codes = np.take_along_axis(self.codes, order, axis=0)
+            self.tables = (sorted_codes.T.copy(),
+                           order.T.astype(np.int32).copy())
+        return self.tables
 
 
 def _bucket_slices(sorted_codes: np.ndarray, q_codes: np.ndarray,
@@ -145,7 +158,7 @@ class WLSHIndex:
         self._built: dict[int, BuiltGroup] = {}
         if materialize:
             for gi in range(len(self.part.groups)):
-                self._group(gi)
+                self._group(gi).sorted_tables()
 
     # ------------------------------------------------------------------ build
 
@@ -171,17 +184,8 @@ class WLSHIndex:
             c=self.cfg.c,
             seed=self.seed + 7919 * gi,
         )
-        codes = hash_codes_np(self.data, fam)  # (n, beta)
-        order = np.argsort(codes, axis=0, kind="stable")  # (n, beta)
-        sorted_codes = np.take_along_axis(codes, order, axis=0).T.copy()
-        sorted_ids = order.T.astype(np.int32).copy()
-        built = BuiltGroup(
-            plan=plan,
-            fam=fam,
-            sorted_codes=sorted_codes,
-            sorted_ids=sorted_ids,
-            codes=codes,
-        )
+        built = BuiltGroup(plan=plan, fam=fam,
+                           codes=hash_codes_np(self.data, fam))  # (n, beta)
         self._built[gi] = built
         return built
 
@@ -288,8 +292,7 @@ class WLSHIndex:
 
         q = np.asarray(q, dtype=np.float32)
         q_codes = hash_codes_np(q[None, :], built.fam)[0][:beta_i]
-        sc = built.sorted_codes[:beta_i]
-        sids = built.sorted_ids[:beta_i]
+        sc, sids = (t[:beta_i] for t in built.sorted_tables())
 
         counts = np.zeros(n, dtype=np.int32)
         checked = np.zeros(n, dtype=bool)
@@ -390,9 +393,9 @@ class WLSHIndex:
         freq = np.zeros(n, dtype=bool)
         n_collisions = 0
         stop_level, n_checked, found_k = n_levels, 0, False
-        for j, inc in enumerate(_level_collisions(
-                built.sorted_codes[:beta_i], built.sorted_ids[:beta_i],
-                q_codes, c, n_levels)):
+        sc, sids = (t[:beta_i] for t in built.sorted_tables())
+        for j, inc in enumerate(
+                _level_collisions(sc, sids, q_codes, c, n_levels)):
             n_collisions += inc.size
             counts += np.bincount(inc, minlength=n)
             new = np.where((counts >= mu_i) & ~freq)[0]

@@ -3,6 +3,8 @@ path agreement, C2LSH degeneration, I/O accounting."""
 
 from __future__ import annotations
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -217,3 +219,80 @@ def test_weight_set_generator_properties():
     for s in range(4):
         sub = W[s * 5 : (s + 1) * 5]
         assert np.all(sub.max(axis=0) - sub.min(axis=0) <= 9.0 / 5 + 1e-9)
+
+
+# ------------------------------------------------------ lazy sorted tables
+
+def _lazy_eager(p, tau, materialize):
+    data = make_dataset(n=2_000, d=16, seed=31)
+    weights = make_weight_set(size=8, d=16, n_subset=2, n_subrange=10,
+                              seed=32)
+    cfg = PlanConfig(p=p, c=3, n=len(data), gamma_n=100.0)
+    return WLSHIndex(data, weights, cfg, tau=tau, v=4, v_prime=4, seed=33,
+                     materialize=materialize)
+
+
+def _tabled_groups(idx):
+    return sorted(gi for gi, b in idx._built.items() if b.tables is not None)
+
+
+def test_export_builds_no_sorted_tables():
+    """The serving-plan export hashes every group but sorts none."""
+    idx = _lazy_eager(2.0, 500.0, materialize=False)
+    idx.export_serving_plan()
+    assert sorted(idx._built) == list(range(len(idx.part.groups)))
+    assert _tabled_groups(idx) == []
+
+
+def test_host_search_builds_only_its_groups_tables():
+    idx = _lazy_eager(2.0, 500.0, materialize=False)
+    assert len(idx.part.groups) > 1
+    idx.export_serving_plan()
+    wid = 0
+    idx.search_dense(idx.data[0], weight_id=wid, k=3)
+    assert _tabled_groups(idx) == [int(idx.part.group_of[wid])]
+    tables = idx._built[int(idx.part.group_of[wid])].tables
+    idx.search(idx.data[1], weight_id=wid, k=3)
+    assert idx._built[int(idx.part.group_of[wid])].tables is tables
+
+
+def test_materialize_and_reset_build_every_groups_tables():
+    idx = _lazy_eager(2.0, 500.0, materialize=True)
+    assert _tabled_groups(idx) == list(range(len(idx.part.groups)))
+    idx._built = {}
+    idx.search(idx.data[0], weight_id=0, k=3)
+    assert _tabled_groups(idx) == [int(idx.part.group_of[0])]
+
+
+@pytest.mark.parametrize("p,tau", [(2.0, 500.0), (1.0, 1_000.0),
+                                   (0.5, 2_000.0)])
+def test_exported_codes_match_materialized(p, tau):
+    lazy = _lazy_eager(p, tau, materialize=False).export_serving_plan()
+    eager = _lazy_eager(p, tau, materialize=True).export_serving_plan()
+    assert len(lazy.groups) == len(eager.groups)
+    for a, b in zip(lazy.groups, eager.groups):
+        assert a.codes.dtype == b.codes.dtype
+        np.testing.assert_array_equal(a.codes, b.codes)
+        np.testing.assert_array_equal(a.proj, b.proj)
+
+
+@pytest.mark.parametrize("p,tau", [(2.0, 500.0), (1.0, 1_000.0),
+                                   (0.5, 2_000.0)])
+def test_lazy_and_eager_search_agree(p, tau):
+    """Host answers and stats do not depend on when the tables are built."""
+    lazy = _lazy_eager(p, tau, materialize=False)
+    lazy.export_serving_plan()
+    eager = _lazy_eager(p, tau, materialize=True)
+    rng = np.random.default_rng(34)
+    for qi in range(12):
+        q = lazy.data[rng.integers(lazy.n)] + rng.normal(0, 30.0, 16)
+        wid = int(rng.integers(len(lazy.weights)))
+        k = (1, 5, 10)[qi % 3]
+        for name in ("search", "search_dense"):
+            a = getattr(lazy, name)(q, weight_id=wid, k=k)
+            b = getattr(eager, name)(q, weight_id=wid, k=k)
+            np.testing.assert_array_equal(a.ids, b.ids)
+            np.testing.assert_array_equal(a.dists, b.dists)
+            # assert_equal treats search_dense's NaN io_blocks as equal
+            np.testing.assert_equal(dataclasses.asdict(a.stats),
+                                    dataclasses.asdict(b.stats))
