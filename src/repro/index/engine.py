@@ -42,7 +42,9 @@ an *input*: the retrieval service encodes on the host (float64, bit-exact
 against the planner's codes) while standalone callers use
 ``encode_queries``.  Per-query beta_q/levels_q also make shape padding
 exact, so groups whose (beta, n_levels) round to the same buckets share one
-compiled step via ``QueryStepCache``.
+compiled step via ``QueryStepCache``.  The batch's live-row count
+``n_live`` is a run-time input too: the Pallas kernels run no grid step
+for the padding rows past it, with no new compiled shape.
 """
 
 from __future__ import annotations
@@ -118,6 +120,7 @@ def shardings(mesh: Mesh):
         ),
         "queries": NamedSharding(mesh, P(None, None)),
         "q_meta": NamedSharding(mesh, P(None)),
+        "scalar": NamedSharding(mesh, P()),
         "out": NamedSharding(mesh, P(None, None)),
     }
 
@@ -140,6 +143,7 @@ def _query_shard(
     r_min,  # (q_loc,) f32
     beta_q,  # (q_loc,) int32 per-member beta_{W_i}
     levels_q,  # (q_loc,) int32 per-member level cap (<= cfg.n_levels)
+    n_live,  # () int32 live query rows; the rest repeat them as padding
     cfg: IndexConfig,
     mesh_axes: tuple[str, ...],
     axis_sizes: tuple[int, ...],
@@ -186,7 +190,8 @@ def _query_shard(
             hf, hg = ops.fused_query_block(
                 cb, pb, codes_q, qf32, wf32, mu, r_min, beta_q,
                 boff=boff, n_valid=n_valid, c=c, n_levels=L, p=cfg.p,
-                use_pallas=path.pallas, interpret=path.interpret,
+                n_live=n_live, use_pallas=path.pallas,
+                interpret=path.interpret,
                 unroll=cfg.analysis_unroll,
             )
             return (hist_f + hf, hist_g + hg), None
@@ -239,7 +244,8 @@ def _query_shard(
             scores = ops.fused_query_block(
                 cb, pb, codes_q, qf32, wf32, mu, r_min, beta_q,
                 boff=boff, n_valid=n_valid, c=c, n_levels=L, p=cfg.p,
-                stop=stop, use_pallas=path.pallas, interpret=path.interpret,
+                stop=stop, n_live=n_live, use_pallas=path.pallas,
+                interpret=path.interpret,
                 unroll=cfg.analysis_unroll,
             )
         else:
@@ -315,8 +321,12 @@ def encode_queries(state: QueryState, queries) -> jax.Array:
 
 def make_query_step(mesh: Mesh, cfg: IndexConfig):
     """jit'd sharded query step:
-    (state, queries, q_codes, q_weight, mu, r_min, beta_q, levels_q) ->
-    (dists (Q,k), ids (Q,k), stop (Q,), n_checked (Q,))."""
+    (state, queries, q_codes, q_weight, mu, r_min, beta_q, levels_q, n_live)
+    -> (dists (Q,k), ids (Q,k), stop (Q,), n_checked (Q,)).
+
+    ``n_live`` (() int32, replicated) counts the batch's live rows; rows
+    at or past it are padding, and the Pallas kernels skip them (their
+    outputs are defined but not answers).  A full batch passes Q."""
     pa = _point_axes(mesh)
     sh = shardings(mesh)
     # Strict row placement (distributed.group_sharding): a capacity that
@@ -348,6 +358,7 @@ def make_query_step(mesh: Mesh, cfg: IndexConfig):
             P(None),
             P(None),
             P(None),
+            P(),
         ),
         out_specs=(P(None, None), P(None, None), P(None), P(None)),
     )
@@ -362,6 +373,7 @@ def make_query_step(mesh: Mesh, cfg: IndexConfig):
             sh["q_meta"],
             sh["q_meta"],
             sh["q_meta"],
+            sh["scalar"],
         ),
         out_shardings=(sh["out"], sh["out"], sh["q_meta"], sh["q_meta"]),
     )
@@ -420,4 +432,5 @@ def query_input_specs(cfg: IndexConfig):
         r_min=jax.ShapeDtypeStruct((cfg.q_batch,), jnp.float32),
         beta_q=jax.ShapeDtypeStruct((cfg.q_batch,), jnp.int32),
         levels_q=jax.ShapeDtypeStruct((cfg.q_batch,), jnp.int32),
+        n_live=jax.ShapeDtypeStruct((), jnp.int32),
     )

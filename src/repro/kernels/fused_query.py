@@ -23,8 +23,14 @@ floor(b / c^j) test, with every integer division on the (1, beta) query
 row and none on the (BN, beta) point tile, where the TPU would expand it
 into long multiply/shift sequences per vreg.
 
-Grid: (Q, block/BN).  Query code row (1, beta) and point codes (BN, beta)
-stay whole in the lane axis, as do the (1, d)/(BN, d) vector tiles.
+Grid: (n_live, block/BN).  ``n_live`` is the launch's count of live
+query rows, a run-time int32 scalar that becomes a dynamic grid bound:
+the serving batch pads to Q rows by repeating live ones, and a step for
+a padding row would compute an answer nobody reads, so none is run.
+Rows at or past ``n_live`` are defined by the wrapper instead (zero
+histograms, +inf scores).  Query code row (1, beta) and point codes
+(BN, beta) stay whole in the lane axis, as do the (1, d)/(BN, d) vector
+tiles.
 Per-query operands and outputs are passed as (Q, 1, X) with the query
 axis squeezed out of the block, so every block's last two dims equal the
 array's and Mosaic accepts any Q (a (1, X) block of a (Q, X) array is
@@ -229,6 +235,17 @@ def _per_query_rows(x, dtype):
     return x.astype(dtype).reshape(x.shape[0], 1, x.shape[1])
 
 
+def _live_rows(q: int, n_live):
+    """(grid's query bound, (Q, 1) mask of the rows the kernel writes).
+
+    ``n_live`` None means all Q rows.  The bound is clipped to [0, Q] so
+    no grid step indexes past the batch.
+    """
+    n_live = jnp.clip(jnp.asarray(q if n_live is None else n_live,
+                                  jnp.int32), 0, q)
+    return n_live, jnp.arange(q, dtype=jnp.int32)[:, None] < n_live
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("c", "n_levels", "p", "bn", "n_rows", "interpret"),
@@ -250,8 +267,12 @@ def fused_query_hist_pallas(
     n_rows: int,  # live rows in the block before padding
     bn: int = 256,
     interpret: bool = False,
+    n_live=None,  # () int32 live query rows; None = all Q
 ):
-    """Pass-1 fused block step -> (hist_f, hist_g), each (Q, nbins)."""
+    """Pass-1 fused block step -> (hist_f, hist_g), each (Q, nbins).
+
+    Rows at or past ``n_live`` run no grid step and read all zero.
+    """
     b_pad, beta = codes_p.shape
     q, d = queries.shape
     bn = min(bn, b_pad)
@@ -265,9 +286,10 @@ def fused_query_hist_pallas(
     tiles, smem_q, smem_g = _specs(beta, d, bn)
     out_spec = pl.BlockSpec((None, 1, n_bins), lambda iq, ip: (iq, 0, 0))
     out_shape = jax.ShapeDtypeStruct((q, 1, n_bins), jnp.int32)
+    grid_q, live = _live_rows(q, n_live)
     hf, hg = pl.pallas_call(
         kernel,
-        grid=(q, n_tiles),
+        grid=(grid_q, n_tiles),
         in_specs=tiles + [smem_q, smem_q, smem_q, smem_g, smem_g],
         out_specs=(out_spec, out_spec),
         out_shape=(out_shape, out_shape),
@@ -292,7 +314,8 @@ def fused_query_hist_pallas(
         _as_col(boff, jnp.int32),
         _as_col(n_valid, jnp.int32),
     )
-    return hf.reshape(q, n_bins), hg.reshape(q, n_bins)
+    return (jnp.where(live, hf.reshape(q, n_bins), 0),
+            jnp.where(live, hg.reshape(q, n_bins), 0))
 
 
 @functools.partial(
@@ -316,8 +339,12 @@ def fused_query_scores_pallas(
     n_rows: int,
     bn: int = 256,
     interpret: bool = False,
+    n_live=None,  # () int32 live query rows; None = all Q
 ):
-    """Pass-2 fused block step -> (Q, B_pad) stop-masked distances."""
+    """Pass-2 fused block step -> (Q, B_pad) stop-masked distances.
+
+    Rows at or past ``n_live`` run no grid step and read +inf.
+    """
     b_pad, beta = codes_p.shape
     q, d = queries.shape
     bn = min(bn, b_pad)
@@ -327,9 +354,10 @@ def fused_query_scores_pallas(
         n_rows=int(n_rows),
     )
     tiles, smem_q, smem_g = _specs(beta, d, bn)
+    grid_q, live = _live_rows(q, n_live)
     out = pl.pallas_call(
         kernel,
-        grid=(q, b_pad // bn),
+        grid=(grid_q, b_pad // bn),
         in_specs=tiles + [smem_q, smem_q, smem_q, smem_g, smem_g],
         out_specs=pl.BlockSpec((None, 1, bn), lambda iq, ip: (iq, 0, ip)),
         out_shape=jax.ShapeDtypeStruct((q, 1, b_pad), jnp.float32),
@@ -350,4 +378,4 @@ def fused_query_scores_pallas(
         _as_col(boff, jnp.int32),
         _as_col(n_valid, jnp.int32),
     )
-    return out.reshape(q, b_pad)
+    return jnp.where(live, out.reshape(q, b_pad), jnp.inf)

@@ -164,6 +164,7 @@ def fused_query_block(
     n_levels: int,
     p: float,
     stop=None,  # None = pass-1 (histograms); (Q,) int32 = pass-2 (scores)
+    n_live=None,  # () int32 live query rows (rows >= it are padding); None = Q
     use_pallas: bool | str | None = None,
     interpret: bool | None = None,
     bn: int = 256,
@@ -182,7 +183,9 @@ def fused_query_block(
     The reference route is the fused XLA composite in ref.py, which
     reuses the unfused engine's distance helpers on identical shapes and
     is therefore bit-exact with the unfused scan.  The Pallas route runs
-    the whole step as one kernel launch (see fused_query.py).
+    the whole step as one kernel launch (see fused_query.py); there only,
+    rows at or past ``n_live`` run no grid step and read zero histograms
+    and +inf scores.  The reference route ignores ``n_live``.
     """
     use_pallas, interpret = _resolve_flags(use_pallas, interpret)
     b, _ = codes_p.shape
@@ -221,11 +224,12 @@ def fused_query_block(
         hf, hg = fused_query_hist_pallas(
             cp, xp, codes_q, qsp, wp, mu, beta_q, r_min, boff, n_valid,
             c=c, n_levels=n_levels, p=p, n_rows=b, bn=bn,
-            interpret=interpret,
+            interpret=interpret, n_live=n_live,
         )
         return hf[:, : n_levels + 2], hg[:, : n_levels + 2]
     out = fused_query_scores_pallas(
         cp, xp, codes_q, qsp, wp, mu, beta_q, stop, boff, n_valid,
         c=c, n_levels=n_levels, p=p, n_rows=b, bn=bn, interpret=interpret,
+        n_live=n_live,
     )
     return out[:, :b]

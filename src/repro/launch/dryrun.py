@@ -232,7 +232,7 @@ def _lower_wlsh(cfg, shape, mesh, mesh_name, overrides: dict | None = None):
         lowered = step.lower(
             specs["state"], specs["queries"], specs["q_codes"],
             specs["q_weight"], specs["mu"], specs["r_min"],
-            specs["beta_q"], specs["levels_q"],
+            specs["beta_q"], specs["levels_q"], specs["n_live"],
         )
     compiled = lowered.compile()
     return lowered, compiled, chips, {"index_cfg": dataclasses.asdict(icfg)}

@@ -836,10 +836,12 @@ class Batcher:
         """One compiled-step launch for 1..q_batch same-group requests.
 
         Pads ragged input by cycling the real rows, encodes the padded
-        batch (row-independent, so padding cannot perturb real rows), and
-        returns ``(ids, dists, stop_levels, n_checked)`` sliced back to the
-        real rows.  Both frontends answer every query through this method,
-        which is what makes them bit-exact on identical traffic.
+        batch (row-independent, so padding cannot perturb real rows),
+        launches with the real row count as the step's ``n_live`` (the
+        scan kernels skip the padding rows), and returns ``(ids, dists,
+        stop_levels, n_checked)`` sliced back to the real rows.  Both
+        frontends answer every query through this method, which is what
+        makes them bit-exact on identical traffic.
 
         ``rung`` serves the batch at a degradation rung of the (c, k)
         relaxation ladder: the same group state, a pre-compiled relaxed
@@ -922,6 +924,7 @@ class Batcher:
                     jnp.asarray(
                         g.n_levels_members[slots].astype(np.int32)
                     ),
+                    jnp.asarray(real, jnp.int32),
                 )
                 # materialize before releasing the lease: the state must
                 # stay resident until the device has finished reading it

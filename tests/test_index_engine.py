@@ -92,6 +92,7 @@ def test_engine_matches_host_oracle(setup):
         jnp.asarray(r_mins, jnp.float32),
         jnp.asarray(betas, jnp.int32),
         jnp.asarray(levels, jnp.int32),
+        jnp.int32(nq),
     )
     dists, ids, stop = np.asarray(dists), np.asarray(ids), np.asarray(stop)
 
@@ -128,9 +129,35 @@ def test_engine_self_query(setup):
         jnp.asarray([built.plan.r_min_members[slot]] * 4, jnp.float32),
         jnp.asarray([beta_i] * 4, jnp.int32),
         jnp.asarray([int(built.plan.n_levels[slot])] * 4, jnp.int32),
+        jnp.int32(4),
     )
     np.testing.assert_array_equal(np.asarray(ids)[:, 0], pids)
     assert np.all(np.asarray(dists)[:, 0] < 1e-3)
+
+
+def _step_args(host, built, state, data, wids, seed):
+    """A query step's inputs after ``state`` and before ``n_live``: noisy
+    corpus rows as queries, one per member id in ``wids``."""
+    rng = np.random.default_rng(seed)
+    qpts = data[rng.choice(len(data), len(wids), replace=False)].astype(
+        np.float32)
+    qpts += rng.normal(0, 3.0, qpts.shape).astype(np.float32)
+    mus, r_mins, betas, levels = [], [], [], []
+    for w in wids:
+        _, slot, beta_i, mu_i = host._member_params(w)
+        mus.append(mu_i)
+        r_mins.append(built.plan.r_min_members[slot])
+        betas.append(beta_i)
+        levels.append(int(built.plan.n_levels[slot]))
+    return (
+        jnp.asarray(qpts),
+        encode_queries(state, qpts),
+        jnp.asarray(np.stack([host.weights[w] for w in wids]), jnp.float32),
+        jnp.asarray(mus, jnp.int32),
+        jnp.asarray(r_mins, jnp.float32),
+        jnp.asarray(betas, jnp.int32),
+        jnp.asarray(levels, jnp.int32),
+    )
 
 
 @pytest.mark.parametrize("mode", [None, "interpret"], ids=["auto", "interpret"])
@@ -145,27 +172,8 @@ def test_engine_fused_paths_bit_exact(setup, mode):
     icfg, state, step, built = _engine_for_group(host, mesh, gi, data, k)
 
     wids = [int(w) for w in built.plan.member_ids[:4]]
-    nq = len(wids)
-    rng = np.random.default_rng(47)
-    qpts = data[rng.choice(len(data), nq, replace=False)].astype(np.float32)
-    qpts += rng.normal(0, 3.0, qpts.shape).astype(np.float32)
-    q_weight = np.stack([host.weights[w] for w in wids]).astype(np.float32)
-    mus, r_mins, betas, levels = [], [], [], []
-    for w in wids:
-        _, slot, beta_i, mu_i = host._member_params(w)
-        mus.append(mu_i)
-        r_mins.append(built.plan.r_min_members[slot])
-        betas.append(beta_i)
-        levels.append(int(built.plan.n_levels[slot]))
-    args = (
-        jnp.asarray(qpts),
-        encode_queries(state, qpts),
-        jnp.asarray(q_weight),
-        jnp.asarray(mus, jnp.int32),
-        jnp.asarray(r_mins, jnp.float32),
-        jnp.asarray(betas, jnp.int32),
-        jnp.asarray(levels, jnp.int32),
-    )
+    args = (*_step_args(host, built, state, data, wids, seed=47),
+            jnp.int32(len(wids)))
     want = step(state, *args)  # the unfused oracle (use_pallas=False)
 
     fcfg = dataclasses.replace(icfg, use_pallas=mode)
@@ -178,6 +186,33 @@ def test_engine_fused_paths_bit_exact(setup, mode):
             np.asarray(a), np.asarray(b),
             err_msg=f"fused path ({mode}) diverged from unfused on {name}",
         )
+
+
+@pytest.mark.parametrize("n_live", [1, 3])
+def test_engine_n_live_answers_live_rows_as_a_full_batch(setup, n_live):
+    """A Pallas (interpret) step told that only ``n_live`` of its Q rows are
+    live answers those rows exactly as the full batch does: same ids,
+    dists, stop levels and n_checked.  The skipped rows come back with
+    empty histograms (n_checked 0) and no candidates (dists +inf)."""
+    data, weights, cfg, host, mesh = setup
+    k = 5
+    gi = int(host.part.group_of[0])
+    icfg, _, _, built = _engine_for_group(host, mesh, gi, data, k)
+    icfg = dataclasses.replace(icfg, use_pallas="interpret")
+    state = build_state(mesh, icfg, data, built.fam)
+    step = make_query_step(mesh, icfg)
+
+    members = built.plan.member_ids
+    wids = [int(members[i % len(members)]) for i in range(icfg.q_batch)]
+    args = _step_args(host, built, state, data, wids, seed=53)
+    full = step(state, *args, jnp.int32(icfg.q_batch))
+    part = step(state, *args, jnp.int32(n_live))
+    for name, a, b in zip(("dists", "ids", "stop", "n_checked"), full, part):
+        np.testing.assert_array_equal(
+            np.asarray(a)[:n_live], np.asarray(b)[:n_live],
+            err_msg=f"live rows diverged under n_live={n_live} on {name}")
+    assert np.all(np.isposinf(np.asarray(part[0])[n_live:]))
+    assert np.all(np.asarray(part[3])[n_live:] == 0)
 
 
 def test_budget_derived_from_gamma():

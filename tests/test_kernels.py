@@ -305,6 +305,45 @@ def test_fused_streaming_watermark(p):
     np.testing.assert_array_equal(np.isfinite(s0), np.isfinite(s1))
 
 
+@pytest.mark.parametrize("p", [2.0, 1.0])
+@pytest.mark.parametrize("kind", ["hist", "scores"])
+@pytest.mark.parametrize("n_live", [1, 3, 8])
+def test_fused_n_live_skips_padding_rows(n_live, kind, p):
+    """With ``n_live`` < Q the kernels answer the live rows exactly as the
+    full grid does, and rows at or past it read zero histograms / +inf
+    scores.  Live rows also match the XLA reference: histograms and p = 1
+    scores (d a lane multiple) bit for bit, p = 2 scores as tightly as
+    ``test_fused_scores_pallas_equals_ref`` holds them."""
+    n, d, beta, Q = 200, 128, 40, 8
+    cp, cq, pts, qs, qw, mu, beta_q, r_min, stop = _mk_fused(
+        n, d, beta, Q, seed=21)
+    kw = dict(boff=0, n_valid=n - 9, c=2, n_levels=8, p=p,
+              stop=stop if kind == "scores" else None)
+    args = (cp, pts, cq, qs, qw, mu, r_min, beta_q)
+    want = ops.fused_query_block(*args, use_pallas=False, **kw)
+    full = ops.fused_query_block(*args, use_pallas=True, interpret=True,
+                                 bn=128, **kw)
+    got = ops.fused_query_block(*args, use_pallas=True, interpret=True,
+                                bn=128, n_live=jnp.int32(n_live), **kw)
+    if kind == "hist":
+        for w, f, g in zip(want, full, got):
+            w, f, g = np.array(w), np.array(f), np.array(g)
+            np.testing.assert_array_equal(g[:n_live], f[:n_live])
+            np.testing.assert_array_equal(g[:n_live], w[:n_live])
+            assert np.all(g[n_live:] == 0)
+        return
+    w, f, g = np.array(want), np.array(full), np.array(got)
+    np.testing.assert_array_equal(g[:n_live], f[:n_live])
+    assert np.all(np.isposinf(g[n_live:]))
+    fin = np.isfinite(w[:n_live])
+    np.testing.assert_array_equal(fin, np.isfinite(g[:n_live]))
+    if p == 2.0:
+        np.testing.assert_allclose(w[:n_live][fin], g[:n_live][fin],
+                                   rtol=2e-4, atol=2e-2)
+    else:
+        np.testing.assert_array_equal(w[:n_live][fin], g[:n_live][fin])
+
+
 def test_fused_ref_matches_unfused_stages():
     """The fused XLA composite vs the seed-era separate stages.
 
@@ -477,6 +516,32 @@ def test_fused_kernel_divides_no_point_tile(kind):
     shapes = {tuple(v.aval.shape) for e in divs for v in e.outvars}
     assert (1, beta) in shapes  # the query row's bucket chain is seen
     assert (bn, beta) not in shapes
+
+
+@pytest.mark.parametrize("kind", ["hist", "scores"])
+def test_fused_grid_query_bound_is_run_time(kind):
+    """The grid is (n_live, n_tiles): the query bound is read at run time
+    (one dynamic bound, so ``n_live`` adds no compiled shape) and the
+    tile axis is the block's, so a full batch runs Q x n_tiles steps."""
+    from repro.kernels import fused_query as fq
+
+    rows, bn, beta, d, Q, L = 512, 256, 480, 128, 8, 14
+    fn = {"hist": fq.fused_query_hist_pallas,
+          "scores": fq.fused_query_scores_pallas}[kind]
+    per_q = jnp.zeros((Q,), jnp.int32)
+    last = jnp.zeros((Q,), jnp.float32 if kind == "hist" else jnp.int32)
+    jaxpr = jax.make_jaxpr(functools.partial(
+        fn, c=3, n_levels=L, p=2.0, n_rows=rows, bn=bn))(
+        jnp.zeros((rows, beta), jnp.int32), jnp.zeros((rows, d)),
+        jnp.zeros((Q, beta), jnp.int32), jnp.zeros((Q, d)),
+        jnp.zeros((Q, d)), per_q, per_q, last, jnp.int32(0), jnp.int32(0),
+        n_live=jnp.int32(Q))
+    (eqn,) = [e for e in _eqns(jaxpr.jaxpr)
+              if e.primitive.name == "pallas_call"]
+    grid_mapping = eqn.params["grid_mapping"]
+    assert grid_mapping.num_dynamic_grid_bounds == 1
+    assert not isinstance(grid_mapping.grid[0], int)
+    assert grid_mapping.grid[1] == rows // bn
 
 
 def test_hash_encode_matches_host_family():

@@ -71,6 +71,7 @@ def test_sharded_engine_matches_host_oracle_on_8_devices():
             jnp.asarray([built.plan.r_min_members[slot]] * 4, jnp.float32),
             jnp.asarray([beta_i] * 4, jnp.int32),
             jnp.asarray([int(built.plan.n_levels[slot])] * 4, jnp.int32),
+            jnp.int32(4),
         )
         ids = np.asarray(ids)
         assert list(ids[:, 0]) == pids, ids[:, 0]

@@ -127,6 +127,34 @@ def test_ragged_batches_match_aligned(setup):
         np.testing.assert_array_equal(single.dists[0], ragged.dists[qi])
 
 
+def test_run_batch_launches_with_the_real_row_count(setup, monkeypatch):
+    """``run_batch`` pads a ragged batch to ``q_batch`` and tells the step
+    how many rows are real: its last input, ``n_live``, is the count."""
+    data, weights, host, plan, svc = setup
+    batcher = svc.batcher
+    get = batcher.step_cache.get
+    seen = []
+
+    def spy_get(mesh, cfg):
+        step = get(mesh, cfg)
+
+        def spy(*args):
+            seen.append((len(args[1]), int(args[-1])))
+            return step(*args)
+
+        return spy
+
+    monkeypatch.setattr(batcher.step_cache, "get", spy_get)
+    gi = int(np.argmax([g.n_members for g in plan.groups]))
+    members = plan.groups[gi].member_ids
+    qb = svc.cfg.q_batch
+    for real in sorted({1, qb - 1, qb}):
+        ids, *_ = batcher.run_batch(gi, data[:real],
+                                    members[np.arange(real) % len(members)])
+        assert len(ids) == real
+        assert seen[-1] == (qb, real)
+
+
 def test_serving_stats_accounting(setup):
     data, weights, host, plan, svc = setup
     svc.reset_stats()

@@ -57,17 +57,19 @@ def one_chip(topo):
 
 
 def _compile_fused(one_chip, kind, beta, d, p, n_levels, rows=ROWS):
-    """Compiled HLO text of one fused pass through the ops wrapper."""
+    """Compiled HLO text of one fused pass through the ops wrapper, with
+    the launch's live-row count ``n_live`` a run-time scalar (the grid's
+    dynamic query bound), as the query step passes it."""
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     def step(codes_p, points, codes_q, queries, q_weight, mu, r_min, beta_q,
-             boff, n_valid, stop):
+             boff, n_valid, stop, n_live):
         return ops.fused_query_block(
             codes_p, points, codes_q, queries, q_weight, mu, r_min, beta_q,
             boff=boff, n_valid=n_valid, c=3, n_levels=n_levels, p=p,
-            stop=stop if kind == "scores" else None,
+            stop=stop if kind == "scores" else None, n_live=n_live,
             use_pallas=True, interpret=False, bn=BN)
 
     return jax.jit(step).lower(
@@ -76,6 +78,7 @@ def _compile_fused(one_chip, kind, beta, d, p, n_levels, rows=ROWS):
         spec((Q, d), jnp.float32), spec((Q,), jnp.int32),
         spec((Q,), jnp.float32), spec((Q,), jnp.int32),
         spec((), jnp.int32), spec((), jnp.int32), spec((Q,), jnp.int32),
+        spec((), jnp.int32),
     ).compile().as_text()
 
 
@@ -95,14 +98,16 @@ def test_fused_kernel_compiles_at_service_batch(one_chip, kind, d, p):
 def test_fused_kernel_compiles_at_cell_shapes(one_chip, kind, beta, d, p,
                                               n_levels):
     """Both fused passes at the benchmark cells' state widths, distance
-    and level count, at Q = 8 and bn = 256."""
+    and level count, at Q = 8 and bn = 256, with the run-time ``n_live``
+    grid bound."""
     hlo = _compile_fused(one_chip, kind, beta, d, p, n_levels)
     assert "tpu_custom_call" in hlo
 
 
 def test_query_step_compiles_with_the_pallas_kernels(topo, monkeypatch):
     """The whole jitted query step on one described chip, with the default
-    (auto) kernel path resolved as it is on a TPU backend."""
+    (auto) kernel path resolved as it is on a TPU backend; its inputs end
+    with the launch's live-row count ``n_live``."""
     monkeypatch.setattr(kplatform, "_backend_cache", "tpu")
     assert kplatform.resolve(None).label == "fused-pallas"
     mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
@@ -112,7 +117,10 @@ def test_query_step_compiles_with_the_pallas_kernels(topo, monkeypatch):
                       n_levels=16, p=2.0, block_n=n, gamma_n=100.0,
                       vec_dtype="float32", use_pallas=None)
     step = make_query_step(mesh, cfg)
-    hlo = step.lower(*query_input_specs(cfg).values()).compile().as_text()
+    specs = query_input_specs(cfg)
+    assert list(specs)[-1] == "n_live"
+    assert specs["n_live"].shape == () and specs["n_live"].dtype == jnp.int32
+    hlo = step.lower(*specs.values()).compile().as_text()
     assert hlo.count("tpu_custom_call") >= 2  # pass 1 and pass 2
 
 
