@@ -131,7 +131,7 @@ def collect() -> dict:
 
     def _service(obs_on: bool):
         svc = RetrievalService(plan, data, cfg=ServiceConfig(
-            k=5, q_batch=8, use_pallas=False,
+            k=5, q_batch=8,
             max_resident_groups=cap, degrade_ladder=ladder,
             recall_sample_rate=1.0 if obs_on else 0.0,
             recall_floor=0.25, obs=obs_on))

@@ -127,7 +127,7 @@ def _build_service(n, d, n_weights, n_subset, seed=0):
     plan = host.export_serving_plan()
     svc = RetrievalService(
         plan, data,
-        cfg=ServiceConfig(k=K, q_batch=Q_BATCH, use_pallas=False),
+        cfg=ServiceConfig(k=K, q_batch=Q_BATCH),
     )
     svc.warmup()
     return data, weights, plan, svc
@@ -190,7 +190,7 @@ _SHARD_CHILD = """
     plan = host.export_serving_plan()
     svc = RetrievalService(plan, data, cfg=ServiceConfig(
         k=%(k)d, q_batch=%(q_batch)d, block_n=63, delta_reserve_rows=5,
-        n_shards=SHARDS, use_pallas=False))
+        n_shards=SHARDS))
     assert svc.mesh.size == SHARDS
     svc.warmup()
     rng = np.random.default_rng(11)
@@ -349,7 +349,7 @@ def run(full: bool = False) -> dict:
         cap = max(1, int(np.ceil(frac * plan.n_groups)))
         psvc = RetrievalService(
             plan, data,
-            cfg=ServiceConfig(k=K, q_batch=Q_BATCH, use_pallas=False,
+            cfg=ServiceConfig(k=K, q_batch=Q_BATCH,
                               max_resident_groups=cap),
         )
         psvc.warmup()  # builds every state once; excess groups host-offload
@@ -397,7 +397,7 @@ def run(full: bool = False) -> dict:
         srng = np.random.default_rng(int(mix * 100) + 17)
         ssvc = RetrievalService(
             plan, data,
-            cfg=ServiceConfig(k=K, q_batch=Q_BATCH, use_pallas=False,
+            cfg=ServiceConfig(k=K, q_batch=Q_BATCH,
                               max_resident_groups=cap5,
                               delta_seal_rows=16,
                               delta_reserve_rows=n_queries),
@@ -479,7 +479,7 @@ def run(full: bool = False) -> dict:
     for label, policy in (("off", None), ("on", DeadlinePrefetch())):
         dsvc = RetrievalService(
             plan, data,
-            cfg=ServiceConfig(k=K, q_batch=Q_BATCH, use_pallas=False,
+            cfg=ServiceConfig(k=K, q_batch=Q_BATCH,
                               max_resident_groups=cap6),
         )
         dsvc.warmup()
@@ -566,7 +566,7 @@ def run(full: bool = False) -> dict:
     qos_results = {}
     for label, degradable in (("off", False), ("on", True)):
         qsvc = RetrievalService(plan, data, cfg=ServiceConfig(
-            k=K, q_batch=Q_BATCH, use_pallas=False,
+            k=K, q_batch=Q_BATCH,
             degrade_ladder=ladder8))
         qsvc.warmup()  # compiles every rung's step ahead of traffic
         qsvc.reset_stats()
@@ -660,7 +660,7 @@ def run(full: bool = False) -> dict:
     def _obs_run(obs_on: bool) -> dict:
         osvc = RetrievalService(
             plan, data,
-            cfg=ServiceConfig(k=K, q_batch=Q_BATCH, use_pallas=False,
+            cfg=ServiceConfig(k=K, q_batch=Q_BATCH,
                               max_resident_groups=cap6, obs=obs_on),
         )
         osvc.warmup()
@@ -745,7 +745,7 @@ def run(full: bool = False) -> dict:
 
     def _recall_replay(sample_rate: float):
         rsvc = RetrievalService(plan, data, cfg=ServiceConfig(
-            k=K, q_batch=Q_BATCH, use_pallas=False,
+            k=K, q_batch=Q_BATCH,
             degrade_ladder=ladder8,
             recall_sample_rate=sample_rate,
             recall_floor=RECALL_FLOOR))

@@ -14,10 +14,10 @@ One chip runs these phases, and any failure exits non-zero:
 
   device     a TPU is required; there is no CPU fallback
   main       SIFT-1M's shape as listed by ann-benchmarks (arXiv:1807.05614):
-             d = 128, p = 2, k = 10, |S| = 8 weights in 2 subsets, default
-             kernel path; the row count is cut to what the host planner's
-             memory and the run's time limit allow (``N_MAIN``)
-  path       the resolved kernel path is compiled ``fused-pallas`` and the
+             d = 128, p = 2, k = 10, |S| = 8 weights in 2 subsets; the
+             row count is cut to what the host planner's memory and the
+             run's time limit allow (``N_MAIN``)
+  path       the backend's scan mode is compiled ``pallas`` and the
              compiled query step holds a ``tpu_custom_call``
   p=1, p=0.5 the same traffic shape under the other exponents, each with
              its own plan
@@ -102,17 +102,16 @@ def _check_path(svc) -> None:
     from repro.index.engine import query_input_specs
     from repro.kernels import platform
 
-    label = platform.resolve(svc.cfg.use_pallas).label
-    if label != "fused-pallas":
-        raise RuntimeError(f"kernel path resolved to {label!r}, "
-                           f"expected 'fused-pallas'")
+    mode = platform.scan_mode()
+    if mode != "pallas":
+        raise RuntimeError(f"scan mode is {mode!r}, expected 'pallas'")
     cfg = svc.group_config(0)
     step = svc.step_cache.get(svc.mesh, cfg)
     hlo = step.lower(*query_input_specs(cfg).values()).compile().as_text()
     n_calls = hlo.count("tpu_custom_call")
     if not n_calls:
         raise RuntimeError("compiled query step holds no tpu_custom_call")
-    print(f"path: kernel path {label}, compiled query step for "
+    print(f"path: scan mode {mode}, compiled query step for "
           f"{jax.devices()[0].device_kind} holds {n_calls} "
           f"tpu_custom_call(s)", flush=True)
 

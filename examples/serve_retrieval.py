@@ -84,7 +84,7 @@ def main():
     # the retrieval service serves *every* group behind one front end
     t0 = time.time()
     svc = RetrievalService(
-        plan, corpus, cfg=ServiceConfig(k=k, q_batch=8, use_pallas=False)
+        plan, corpus, cfg=ServiceConfig(k=k, q_batch=8)
     )
     svc.warmup()
     print(f"service: {plan.n_groups} device group states, "

@@ -15,7 +15,7 @@ from .datagen import make_dataset, make_query_set, make_weight_set
 from .derived import derived_sensitivity, ratio_bounds
 from .distances import radius_bounds, weighted_lp, weighted_lp_np
 from .e2lsh import E2LSH
-from .families import LpFamilyParams, hash_codes, hash_codes_np, sample_lp_family
+from .families import LpFamilyParams, hash_codes_np, sample_lp_family
 from .params import PlanConfig, beta_mu, threshold_reduction_factor
 from .partition import PartitionResult, pairwise_beta, partition, tau_min
 from .pstable import pstable_pdf, pstable_pdf_abs, sample_pstable
@@ -37,7 +37,6 @@ __all__ = [
     "beta_mu",
     "collision_prob",
     "derived_sensitivity",
-    "hash_codes",
     "hash_codes_np",
     "make_dataset",
     "make_query_set",

@@ -30,13 +30,11 @@ from __future__ import annotations
 import dataclasses
 import math
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 from .pstable import sample_pstable_np
 
-__all__ = ["LpFamilyParams", "sample_lp_family", "hash_codes_np", "hash_codes"]
+__all__ = ["LpFamilyParams", "sample_lp_family", "hash_codes_np"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -102,17 +100,6 @@ def hash_codes_np(points: np.ndarray, fam: LpFamilyParams) -> np.ndarray:
     return (np.floor(u).astype(np.int64) + fam.b_int.astype(np.int64)).astype(
         np.int32
     )
-
-
-def hash_codes(points, proj, b_int, b_frac, weight, width) -> jax.Array:
-    """Level-1 bucket ids, (n, beta) int32 — JAX reference path.
-
-    The Pallas kernel ``kernels/hash_encode.py`` fuses this; this function is
-    the jnp fallback and the building block for the sharded index builder.
-    """
-    x = points * weight
-    u = (x @ proj) / width + b_frac
-    return jnp.floor(u).astype(jnp.int32) + b_int.astype(jnp.int32)
 
 
 # ----------------------------------------------------------------------------

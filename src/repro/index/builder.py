@@ -22,7 +22,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.families import LpFamilyParams
 from ..core.serving_plan import GroupServingPlan
-from ..kernels import ops
+from ..kernels import ref
 from .config import IndexConfig
 from .engine import QueryState, _point_axes, encode_queries
 
@@ -61,14 +61,13 @@ def fold_center_weight(fam: LpFamilyParams) -> dict[str, np.ndarray]:
 
 
 def _build_fn(points, proj, b_int, b_frac, vec_dtype):
-    codes = ops.hash_encode(
+    codes = ref.hash_encode_ref(
         points.astype(jnp.float32),
-        jnp.ones((points.shape[1],), jnp.float32),
         proj,
         b_int,
         b_frac,
+        jnp.ones((points.shape[1],), jnp.float32),
         1.0,
-        use_pallas=False,  # sharded matmul: XLA path; Pallas on TPU shards
     )
     return codes, points.astype(vec_dtype)
 

@@ -80,11 +80,6 @@ class IndexConfig:
     # At 1B points a larger false-positive budget than the paper's ~k+100
     # is the practical choice — set it here instead of re-deriving gamma.
     vec_dtype: str = "bfloat16"  # stored vectors (verification re-ranks in f32)
-    use_pallas: bool | str | None = None  # kernel path (kernels.platform):
-    # None = auto (fused; compiled Pallas where the backend supports it,
-    # bit-exact fused XLA composite elsewhere), True = fused Pallas
-    # (interpret off-TPU), "interpret" = fused Pallas interpret mode,
-    # False = the seed-era unfused stage-by-stage oracle
     delta_seal_rows: int = 1024  # streaming: an open delta memtable seals
     # into a hashed segment at this row count; not compile-relevant (absent
     # from shape_signature), but part of dataclass equality, so a Batcher
@@ -149,7 +144,7 @@ class IndexConfig:
         return (
             self.n, self.d, self.beta, self.q_batch, self.k, self.c,
             self.n_levels, self.p, self.block_n, self.budget,
-            self.vec_dtype, self.use_pallas, self.analysis_unroll,
+            self.vec_dtype, self.analysis_unroll,
             self.n_shards, self.shard_axis,
         )
 

@@ -1,38 +1,31 @@
-"""Sharded WLSH query engine (the paper's Search, TPU-pod-native).
+"""Sharded WLSH query engine (the paper's Search).
 
-Decomposition (DESIGN.md Sec. 4): point rows -- codes (n, beta) and vectors
-(n, d) -- are sharded over *every* mesh axis ("pod" x "data" x "model"), and
-the query batch is replicated.  Each chip scores all Q queries against its
-n/chips rows, so the (Q, n) work splits perfectly by rows while per-chip
-state stays 1/chips of the index.  At the 1B-point production config the
-codes alone are 2 TB: the first-cut layout (rows over ("pod","data") only,
-queries over "model") left the model axis holding replicas -- 128 GB/chip,
-8x over HBM.  Row-sharding over all axes was perf iteration #1, see
-EXPERIMENTS.md Sec. Perf.  The only communication is
+Point rows -- codes (n, beta) and vectors (n, d) -- are sharded over every
+mesh axis, and the query batch is replicated.  Each device scores all Q
+queries against its n/devices rows, so the (Q, n) work splits by rows
+while per-device state stays 1/devices of the index.  The only
+communication is
 
-  * a psum of per-query level histograms, (Q, L+2) ints -- bytes, and
-  * an all-gather of per-shard top-k rows, (Q, k) -- bytes,
+  * a psum of per-query level histograms, (Q, L+2) ints, and
+  * an all-gather of per-shard top-k rows, (Q, k),
 
 both over all axes.  Per shard the engine streams its code/vector slabs
 through VMEM-sized blocks in two passes (lax.scan):
 
-  pass 1  codes -> freq_level -> per-level frequent/good histograms
-          -> psum -> the paper's stop conditions (k found / budget) -> j*
+  pass 1  codes + vectors -> first-frequent level, distance -> per-level
+          frequent/good histograms -> psum -> the paper's stop conditions
+          (k found / budget) -> j*
   pass 2  codes + vectors -> masked distances (L_freq <= j*) -> running
-          local top-k -> all-gather -> global top-k
+          local top-k -> exact re-rank -> all-gather -> global top-k
 
-Each scan step of both passes dispatches through ``ops.fused_query_block``
-— one launch per block computing level, distance and histogram/mask
-together, so the (q_loc, block) intermediates never round-trip through HBM
-between stages (Pallas kernel on TPU, a bit-exact fused XLA composite
-elsewhere; ``kernels.platform.resolve`` maps ``cfg.use_pallas`` onto the
-path).  ``use_pallas=False`` keeps the seed-era stage-by-stage scan as the
-parity oracle.
+Each scan step of both passes is one ``ops.fused_query_block`` call, which
+computes level, distance and histogram/mask together, so the (q_loc,
+block) intermediates never round-trip through HBM between stages.  The
+backend alone picks its kernel (``kernels.platform.scan_mode``): the
+Pallas kernels on TPU, the fused XLA composite in ref.py elsewhere.
 
-Pass 2 recomputes L_freq instead of materializing the (Q, n_loc) int8
-matrix -- at beta/d ~ 4 this costs ~1.3x compute for ~0 bytes of HBM
-footprint; the single-pass per-level-candidate variant is evaluated in the
-perf log (EXPERIMENTS.md Sec. Perf).
+Pass 2 recomputes L_freq instead of materializing the (Q, n_loc) level
+matrix, trading compute for HBM footprint.
 
 Every query carries its own weight vector, collision threshold mu, radius
 base r_min, table count beta_q and level cap levels_q (the WLSH multi-weight
@@ -59,7 +52,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..distributed import group_sharding
 from ..distributed.sharding import shard_map_nocheck
 from ..kernels import ops, ref
-from ..kernels import platform as kplatform
 from .config import IndexConfig
 
 __all__ = [
@@ -125,15 +117,6 @@ def shardings(mesh: Mesh):
     }
 
 
-# The per-query distance helpers live in kernels.ref so the unfused scan
-# below and the fused XLA composite (ops.fused_query_block's reference
-# route) trace the *same* functions on the same block shapes — which is
-# what makes the two paths bit-exact (f32 gemms are shape-sensitive).
-_log_c = ref.log_c
-_per_query_l2 = ref.per_query_l2
-_per_query_lp = ref.per_query_lp
-
-
 def _query_shard(
     state: QueryState,
     queries,  # (q_loc, d)
@@ -158,62 +141,25 @@ def _query_shard(
 
     codes_blocks = state.codes.reshape(n_blocks, block, cfg.beta)
     point_blocks = state.points.reshape(n_blocks, block, cfg.d)
-    # use_pallas resolves to a concrete kernel path per backend (see
-    # kernels.platform): fused single-launch block steps by default, the
-    # seed-era unfused stage-by-stage scan as the use_pallas=False oracle.
-    path = kplatform.resolve(cfg.use_pallas)
 
     # Global row offsets per block: streaming states reserve row capacity
     # above the live count, and rows >= n_valid must vanish from both
-    # passes (their first-frequent level is forced past every stop level).
+    # passes (the block step excludes them from histograms and scores).
     shard_off = group_sharding.shard_row_offset(mesh_axes, axis_sizes, n_loc)
     boffs = shard_off + jnp.arange(n_blocks, dtype=jnp.int32) * block
     n_valid = state.n_valid.astype(jnp.int32)
-
-    def _masked_freq_level(cb, boff):
-        """(q_loc, block) first-frequent level, dead rows forced to L+1."""
-        lf = ops.freq_level(
-            cb, codes_q, mu, c=c, n_levels=L, beta_q=beta_q,
-            use_pallas=cfg.use_pallas, unroll=cfg.analysis_unroll,
-        )
-        row_ok = (boff + jnp.arange(block, dtype=jnp.int32)) < n_valid
-        return jnp.where(row_ok[None, :], lf, jnp.int32(L + 1))
+    block_step = functools.partial(
+        ops.fused_query_block, codes_q=codes_q, queries=qf32,
+        q_weight=wf32, mu=mu, r_min=r_min, beta_q=beta_q, n_valid=n_valid,
+        c=c, n_levels=L, p=cfg.p, n_live=n_live, unroll=cfg.analysis_unroll,
+    )
 
     # ---- pass 1: level histograms -> stop level ---------------------------
-    # Fused and unfused paths bin dead rows differently (excluded vs parked
-    # at L+1), but the stop logic below only reads bins 0..L, so stop /
-    # n_checked — and therefore ids/dists — are bit-identical either way.
     def pass1(carry, blk):
         hist_f, hist_g = carry
         cb, pb, boff = blk
-        if path.fused:
-            hf, hg = ops.fused_query_block(
-                cb, pb, codes_q, qf32, wf32, mu, r_min, beta_q,
-                boff=boff, n_valid=n_valid, c=c, n_levels=L, p=cfg.p,
-                n_live=n_live, use_pallas=path.pallas,
-                interpret=path.interpret,
-                unroll=cfg.analysis_unroll,
-            )
-            return (hist_f + hf, hist_g + hg), None
-        lf = _masked_freq_level(cb, boff)  # (q_loc, block)
-        if abs(cfg.p - 2.0) < 1e-9:
-            dist = _per_query_l2(qf32, wf32, pb.astype(jnp.float32))
-        else:
-            dist = _per_query_lp(qf32, wf32, pb.astype(jnp.float32), cfg.p)
-        jg = jnp.ceil(
-            jnp.maximum(_log_c(jnp.maximum(dist, 1e-30), c)
-                        - _log_c(c * r_min, c)[:, None], 0.0)
-        ).astype(jnp.int32)
-        good_lvl = jnp.maximum(lf, jg)
-        levels = jnp.arange(L + 2, dtype=jnp.int32)
-        hist_f = hist_f + jnp.sum(
-            (lf[:, :, None] == levels[None, None, :]).astype(jnp.int32), axis=1
-        )
-        hist_g = hist_g + jnp.sum(
-            (good_lvl[:, :, None] == levels[None, None, :]).astype(jnp.int32),
-            axis=1,
-        )
-        return (hist_f, hist_g), None
+        hf, hg = block_step(cb, pb, boff=boff)
+        return (hist_f + hf, hist_g + hg), None
 
     hist0 = jnp.zeros((q_loc, L + 2), jnp.int32)
     (hist_f, hist_g), _ = jax.lax.scan(
@@ -240,22 +186,7 @@ def _query_shard(
     def pass2(carry, blk):
         vals, idx = carry
         cb, pb, boff = blk
-        if path.fused:
-            scores = ops.fused_query_block(
-                cb, pb, codes_q, qf32, wf32, mu, r_min, beta_q,
-                boff=boff, n_valid=n_valid, c=c, n_levels=L, p=cfg.p,
-                stop=stop, n_live=n_live, use_pallas=path.pallas,
-                interpret=path.interpret,
-                unroll=cfg.analysis_unroll,
-            )
-        else:
-            lf = _masked_freq_level(cb, boff)
-            if abs(cfg.p - 2.0) < 1e-9:
-                dist = _per_query_l2(qf32, wf32, pb.astype(jnp.float32))
-            else:
-                dist = _per_query_lp(qf32, wf32, pb.astype(jnp.float32),
-                                     cfg.p)
-            scores = jnp.where(lf <= stop[:, None], dist, jnp.inf)
+        scores = block_step(cb, pb, boff=boff, stop=stop)
         bvals, bidx = jax.lax.top_k(-scores, k)
         bidx = bidx + boff
         vals = jnp.concatenate([vals, -bvals], axis=1)
@@ -308,14 +239,13 @@ def encode_queries(state: QueryState, queries) -> jax.Array:
     retrieval service instead host-encodes in float64 for bit-exactness
     against the planner; this is the standalone/engine-only path.
     """
-    return ops.hash_encode(
+    return ref.hash_encode_ref(
         jnp.asarray(queries, jnp.float32),
-        jnp.ones((state.proj.shape[0],), jnp.float32),
         state.proj,
         state.b_int,
         state.b_frac,
+        jnp.ones((state.proj.shape[0],), jnp.float32),
         1.0,
-        use_pallas=False,
     )
 
 

@@ -117,8 +117,7 @@ def exact_weighted_lp(
     """(Q, m) exact per-query weighted l_p distances, float32.
 
     Coordinate-difference form — the same epilogue the sharded engine
-    re-ranks its top-k survivors with (and the elementwise form of the
-    ``kernels/weighted_lp`` Pallas kernel), *not* the norms+matmul
+    re-ranks its top-k survivors with, *not* the norms+matmul
     expansion whose f32 cancellation error swamps small distances.  Delta
     hits therefore rank against indexed hits on equal footing.
     """

@@ -1,17 +1,13 @@
-"""Pallas TPU kernels for the WLSH hot spots the paper optimizes:
+"""The fused WLSH scan kernels and their oracles.
 
-  hash_encode  — fused weighted projection + bucket quantization (MXU matmul
-                 with floor/offset epilogue); the Preprocess hot loop.
-  freq_level   — fused multi-level collision counting: the C2LSH virtual-
-                 rehashing search collapsed into one VMEM-resident sweep
-                 returning the first frequent level per (query, point).
-  weighted_lp  — candidate scoring for fractional/l_1 distances (p == 2 is
-                 routed to a norms+matmul expansion instead).
-
-``ops`` exposes jit'd padded wrappers with a pure-jnp fallback; ``ref``
-holds the oracles every kernel is tested against (interpret=True on CPU).
+``fused_query`` holds the two Pallas kernels of the query engine's scan
+(pass-1 level histograms, pass-2 stop-masked scores); ``ops`` pads and
+dispatches one scan block to them or to their fused XLA composite, by
+backend (``platform.scan_mode``); ``ref`` holds the pure-jnp oracles the
+kernels are tested against, and the hash the build and query encode run.
 """
 
-from .ops import freq_level, hash_encode, on_tpu, weighted_lp_dist
+from .ops import fused_query_block
+from .platform import scan_mode
 
-__all__ = ["freq_level", "hash_encode", "on_tpu", "weighted_lp_dist"]
+__all__ = ["fused_query_block", "scan_mode"]

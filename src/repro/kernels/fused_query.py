@@ -1,10 +1,9 @@
 """Pallas TPU kernel: fused WLSH query block step (levels + distances).
 
-One launch per scan block replaces the seed pipeline's three (freq_level,
-weighted_lp, histogram / mask) with the (q, block) intermediates held in
-VMEM — the level matrix and the distance matrix never round-trip through
-HBM between stages, which is the memory traffic the LSH scoring pass is
-bound by.  Two modes, one per engine pass:
+One launch per scan block runs three stages (first-frequent level,
+weighted distance, histogram / mask) with the (q, block) intermediates
+held in VMEM — the level matrix and the distance matrix never round-trip
+through HBM between stages.  Two modes, one per engine pass:
 
   pass 1 (hist):   codes + points tile -> first-frequent level, weighted
                    l_p distance, good-level ceil, and per-level one-hot

@@ -1,9 +1,10 @@
-"""Pure-jnp oracles for every Pallas kernel in this package.
+"""Pure-jnp oracles: the semantics the fused scan kernels must match.
 
-These define the semantics; kernels must match them exactly (integer
-outputs) or to float tolerance (distances).  Shapes:
+Kernels must match them exactly (integer outputs) or to float tolerance
+(distances).  Shapes:
 
   hash_encode_ref : (n, d) x (d, beta) -> (n, beta) int32 bucket codes
+                    (also the hash the build and ``encode_queries`` run)
   freq_level_ref  : (n, beta) codes x (Q, beta) query codes -> (Q, n) int32
                     first level j (0..n_levels) at which the point is
                     *frequent* for the query (collision count >= mu at
@@ -13,12 +14,9 @@ outputs) or to float tolerance (distances).  Shapes:
 
 The fused-query oracles (``fused_query_hist_ref`` / ``fused_query_scores_ref``)
 define the semantics of one fused block step — first-frequent level, weighted
-distance, good-level histogramming and stop-mask scoring in one composite.
-They are also the *serving* fused path off-TPU: the engine's unfused scan uses
-the exact same ``per_query_l2`` / ``per_query_lp`` helpers on the exact same
-block shapes, so the fused XLA composite is bit-exact with the unfused oracle
-by construction (same HLO subgraphs — f32 gemm results are only reproducible
-at fixed shapes, which is why sharing these helpers matters).
+distance, good-level histogramming and stop-mask scoring in one composite,
+built from the stage oracles above and ``per_query_dist``.  They are also
+the *serving* fused path off-TPU.
 """
 
 from __future__ import annotations
@@ -156,11 +154,11 @@ def per_query_lp(q, w, pts, p: float):
 
 
 def per_query_dist(q, w, pts, p: float):
-    """Per-query-weight distance dispatch shared by every engine path.
+    """Per-query-weight distance: the l2 expansion at p = 2, else l_p.
 
-    The unfused scan and the fused XLA composite must call this very
-    function on the same shapes — that is what makes them bit-exact (f32
-    gemms are shape-sensitive in the last ulp).
+    Tests that rebuild the composite from its stages call this very
+    function on the same shapes, under jit — f32 gemms are
+    shape-sensitive in the last ulp.
     """
     if abs(p - 2.0) < 1e-9:
         return per_query_l2(q, w, pts)
@@ -173,9 +171,7 @@ def _fused_lf(codes_b, codes_q, mu, beta_q, row_ok, c, n_levels, unroll):
     Excluded rows (padding or rows at/after the streaming ``n_valid``
     watermark) get the sentinel ``n_levels + 2`` — past every histogram
     bin the stop logic reads (0..n_levels) and past every reachable stop
-    level, so they vanish from both passes.  (The unfused engine parks
-    dead rows at ``n_levels + 1`` instead; bins 0..n_levels and the final
-    scores are identical either way.)
+    level, so they vanish from both passes.
     """
     lf = freq_level_ref(codes_b, codes_q, mu, c, n_levels, beta_q,
                         unroll=unroll)

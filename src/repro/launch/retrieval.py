@@ -389,7 +389,6 @@ def run(args) -> dict:
                          device_budget_bytes=args.device_budget,
                          delta_seal_rows=args.delta_seal_rows,
                          delta_reserve_rows=reserve,
-                         use_pallas=args.use_pallas,
                          n_shards=args.shards,
                          degrade_ladder=ladder,
                          obs=obs,
@@ -414,8 +413,8 @@ def run(args) -> dict:
         print(f"sharding: {svc.mesh.size} shards over mesh "
               f"{dict(svc.mesh.shape)} ({n_loc} rows/shard, "
               f"collective-merged top-k)")
-    print(f"kernels: {kernel_platform.describe(scfg.use_pallas)} "
-          f"(--use-pallas {args.use_pallas})")
+    print(f"kernels: fused scan, {kernel_platform.scan_mode()} on "
+          f"{kernel_platform.backend()}")
     svc.reset_stats()  # serve-phase cache counters exclude warmup churn
 
     # ---- serve --------------------------------------------------------------
@@ -754,24 +753,7 @@ def parse_args(argv=None):
                          "serving: per-rung observed recall vs its "
                          "ladder bound, shadow-queue accounting, and "
                          "(with --driver) the alert-rule summary")
-    ap.add_argument("--use-pallas", choices=["auto", "on", "off",
-                                             "interpret"], default=None,
-                    help="query kernel path: auto = per-backend fused "
-                         "default (compiled Pallas where supported, fused "
-                         "XLA composite elsewhere), on = fused Pallas "
-                         "(interpret off-TPU), off = unfused reference "
-                         "stages, interpret = fused Pallas in interpret "
-                         "mode (kernel body, any backend)")
-    ap.add_argument("--no-pallas", action="store_true",
-                    help="shorthand for --use-pallas off")
     args = ap.parse_args(argv)
-    if args.no_pallas:
-        if args.use_pallas not in (None, "off"):
-            ap.error("--no-pallas contradicts --use-pallas "
-                     f"{args.use_pallas}")
-        args.use_pallas = "off"
-    if args.use_pallas is None:
-        args.use_pallas = "auto"
     if not 0.0 <= args.insert_rate <= 1.0:
         ap.error(f"--insert-rate must be in [0, 1], got {args.insert_rate}")
     if not 0.0 <= args.recall_sample_rate <= 1.0:

@@ -74,10 +74,6 @@ class ServiceConfig:
     q_batch: int = 8  # compiled batch shape; ragged tails are padded
     block_n: int | None = None  # points per scan block; None = whole shard
     vec_dtype: str = "float32"
-    use_pallas: bool | str | None = None  # kernel path (kernels.platform):
-    # None/"auto" = per-backend fused default, True/"on" = fused Pallas
-    # (interpret off-TPU), False/"off" = unfused oracle, "interpret" =
-    # fused Pallas interpret mode; CLI strings are normalized below
     beta_buckets: tuple[int, ...] | None = None  # None = config.pad_beta
     level_step: int = 4  # level-loop bound rounding (config.pad_levels)
     budget_override: int | None = None  # None = k + ceil(gamma * n)
@@ -134,19 +130,6 @@ class ServiceConfig:
     # feeds the wlsh_recall_bound_margin gauge and the below-bound alert
 
     def __post_init__(self):
-        # normalize the CLI spellings onto the IndexConfig values (frozen
-        # dataclass, hence object.__setattr__)
-        up = self.use_pallas
-        if isinstance(up, str):
-            up = {"auto": None, "on": True, "off": False}.get(
-                up.lower(), up.lower()
-            )
-            object.__setattr__(self, "use_pallas", up)
-        if up not in (None, True, False, "interpret"):
-            raise ValueError(
-                f"use_pallas must be one of auto/on/off/interpret (or "
-                f"None/True/False), got {self.use_pallas!r}"
-            )
         if self.k < 1:
             raise ValueError(f"k must be >= 1, got {self.k}")
         if self.q_batch < 1:
@@ -633,7 +616,6 @@ class Batcher:
                 gamma_n=self.plan.gamma_n,
                 budget_override=self.cfg.budget_override,
                 vec_dtype=self.cfg.vec_dtype,
-                use_pallas=self.cfg.use_pallas,
                 delta_seal_rows=self.cfg.delta_seal_rows,
                 n_shards=self.mesh.size,
                 shard_axis=self.mesh.axis_names[0],

@@ -65,7 +65,7 @@ TINY_OVERRIDES = {
     # XLA_FLAGS; the in-process smoke keeps the single real device
     "--shards": "1",
 }
-_STORE_TRUE = {"--check", "--async", "--no-pallas", "--driver",
+_STORE_TRUE = {"--check", "--async", "--driver",
                "--prefetch", "--qos", "--health"}
 
 

@@ -301,7 +301,7 @@ def test_strict_sharding_refuses_non_dividing_capacity_on_mesh():
     assert jax.device_count() == 8
     mesh = jax.make_mesh((8, 1), ("data", "model"))
     cfg = IndexConfig(n=1003, d=16, beta=32, q_batch=4, k=3, block_n=59,
-                      vec_dtype="float32", use_pallas=False)
+                      vec_dtype="float32")
     try:
         make_query_step(mesh, cfg)
         raise AssertionError("non-dividing capacity must raise")

@@ -26,6 +26,7 @@ from repro.index import (
     pad_beta,
     pad_levels,
 )
+from repro.kernels import platform as kplatform
 
 
 @pytest.fixture(scope="module")
@@ -54,7 +55,6 @@ def _engine_for_group(host: WLSHIndex, mesh, gi: int, data, k: int):
         block_n=256,
         gamma_n=host.cfg.gamma_n,
         vec_dtype="float32",
-        use_pallas=False,
     )
     state = build_state(mesh, icfg, data, built.fam)
     step = make_query_step(mesh, icfg)
@@ -160,36 +160,42 @@ def _step_args(host, built, state, data, wids, seed):
     )
 
 
-@pytest.mark.parametrize("mode", [None, "interpret"], ids=["auto", "interpret"])
-def test_engine_fused_paths_bit_exact(setup, mode):
-    """Fused query step (auto/XLA composite and Pallas interpret) must be
-    bit-exact with the unfused oracle: same ids, dists, stop levels and
-    n_checked.  The exact re-rank plus identical candidate sets absorb any
-    kernel-internal float jitter, so equality is exact, not approximate."""
+@pytest.mark.parametrize("block_n", [256, 512],
+                         ids=["fixture_block", "two_tile_blocks"])
+def test_engine_fused_paths_bit_exact(setup, monkeypatch, block_n):
+    """The Pallas kernel body (interpret mode) inside the engine must be
+    bit-exact with the fused XLA composite the CPU serves: same ids,
+    dists, stop levels and n_checked.  The exact re-rank plus identical
+    candidate sets absorb any kernel-internal float jitter, so equality
+    is exact, not approximate.  Both block sizes split the group's 1,024
+    rows into several scan blocks: four of one kernel row tile each, and
+    two of two tiles each."""
     data, weights, cfg, host, mesh = setup
     k = 5
     gi = int(host.part.group_of[0])
-    icfg, state, step, built = _engine_for_group(host, mesh, gi, data, k)
+    icfg, _, _, built = _engine_for_group(host, mesh, gi, data, k)
+    icfg = dataclasses.replace(icfg, block_n=block_n)
+    state = build_state(mesh, icfg, data, built.fam)
 
     wids = [int(w) for w in built.plan.member_ids[:4]]
     args = (*_step_args(host, built, state, data, wids, seed=47),
             jnp.int32(len(wids)))
-    want = step(state, *args)  # the unfused oracle (use_pallas=False)
+    assert kplatform.scan_mode() == "xla"
+    want = make_query_step(mesh, icfg)(state, *args)
 
-    fcfg = dataclasses.replace(icfg, use_pallas=mode)
-    fstate = build_state(mesh, fcfg, data, built.fam)
-    fstep = make_query_step(mesh, fcfg)
-    got = fstep(fstate, *args)
+    monkeypatch.setattr(kplatform, "scan_mode", lambda: "interpret")
+    got = make_query_step(mesh, icfg)(state, *args)
 
     for name, a, b in zip(("dists", "ids", "stop", "n_checked"), want, got):
         np.testing.assert_array_equal(
             np.asarray(a), np.asarray(b),
-            err_msg=f"fused path ({mode}) diverged from unfused on {name}",
+            err_msg=f"Pallas interpret diverged from the composite on {name}",
         )
 
 
 @pytest.mark.parametrize("n_live", [1, 3])
-def test_engine_n_live_answers_live_rows_as_a_full_batch(setup, n_live):
+def test_engine_n_live_answers_live_rows_as_a_full_batch(setup, monkeypatch,
+                                                        n_live):
     """A Pallas (interpret) step told that only ``n_live`` of its Q rows are
     live answers those rows exactly as the full batch does: same ids,
     dists, stop levels and n_checked.  The skipped rows come back with
@@ -197,10 +203,8 @@ def test_engine_n_live_answers_live_rows_as_a_full_batch(setup, n_live):
     data, weights, cfg, host, mesh = setup
     k = 5
     gi = int(host.part.group_of[0])
-    icfg, _, _, built = _engine_for_group(host, mesh, gi, data, k)
-    icfg = dataclasses.replace(icfg, use_pallas="interpret")
-    state = build_state(mesh, icfg, data, built.fam)
-    step = make_query_step(mesh, icfg)
+    monkeypatch.setattr(kplatform, "scan_mode", lambda: "interpret")
+    icfg, state, step, built = _engine_for_group(host, mesh, gi, data, k)
 
     members = built.plan.member_ids
     wids = [int(members[i % len(members)]) for i in range(icfg.q_batch)]
@@ -257,7 +261,7 @@ def test_build_is_deterministic(setup):
     gi = int(host.part.group_of[0])
     built = host._group(gi)
     icfg = IndexConfig(n=len(data), d=data.shape[1], beta=built.fam.beta,
-                       vec_dtype="float32", use_pallas=False)
+                       vec_dtype="float32")
     s1 = build_state(mesh, icfg, data, built.fam)
     s2 = build_state(mesh, icfg, data, built.fam)
     np.testing.assert_array_equal(np.asarray(s1.codes), np.asarray(s2.codes))

@@ -1,18 +1,15 @@
-"""Pallas kernel sweeps vs the pure-jnp ref.py oracles.
+"""The fused scan kernels vs their pure-jnp ref.py oracles, and the oracles
+themselves vs brute force and the host planner.
 
-Per the kernel contract:
-  * freq_level: exact integer match (no float path after the codes);
-  * hash_encode: exact match except at floor boundaries, where independent
-    f32 summation orders may legitimately differ by one bucket (|diff| <= 1
-    and only where the pre-floor value is within eps of an integer);
-  * weighted_lp: allclose in f32;
-  * fused_query_block: histograms exact-int; scores carry an identical
-    +inf stop-mask and are bit-exact for p != 2 when d is already a lane
-    multiple (no padding), else ulp-tight allclose — padding d changes
-    the f32 reduction tree, and the p = 2 in-body MXU expansion may
-    differ from the XLA gemm in the last ulp.  (Serving bit-exactness
-    does not rest on this: off-TPU the fused path is the XLA composite
-    in ref.py, which shares the unfused engine's helpers exactly.)
+Per the kernel contract, fused_query_block's Pallas body against the
+fused XLA composite: histograms exact-int; scores carry an identical
++inf stop-mask and are bit-exact for p != 2 when d is already a lane
+multiple (no padding), else ulp-tight allclose — padding d changes the
+f32 reduction tree, and the p = 2 in-body MXU expansion may differ from
+the XLA gemm in the last ulp.  (Serving bit-exactness does not rest on
+this: off-TPU the served scan is the XLA composite itself.)  The hash
+oracle matches the planner's float64 codes except at floor boundaries,
+where f32 and f64 summation orders may differ by one bucket.
 
 All Pallas calls run with interpret=True on CPU (the kernel body itself is
 executed), matching how the kernels are validated off-TPU.
@@ -26,18 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hyp import given, settings, st
 
 from repro.kernels import ops, ref
-
-# Pallas-interpret runs grid cells in Python -> keep shapes moderate.
-_SHAPES = [
-    (64, 16, 24, 4),  # (n, d, beta, Q)
-    (300, 40, 70, 9),
-    (257, 33, 128, 3),  # non-multiples exercise wrapper padding
-    (512, 128, 64, 8),
-]
-
 
 def _mk(n, d, beta, Q, seed=0, int_vals=False):
     rng = np.random.default_rng(seed)
@@ -65,44 +52,6 @@ def _boundary_ok(diff, u):
     return bool(np.all(frac[diff != 0] < 1e-2))
 
 
-@pytest.mark.parametrize("shape", _SHAPES, ids=str)
-def test_hash_encode_sweep(shape):
-    n, d, beta, Q = shape
-    pts, _, w, proj, b_int, b_frac = _mk(n, d, beta, Q)
-    width = 37.5
-    got_ref = np.array(
-        ops.hash_encode(pts, w, proj, b_int, b_frac, width, use_pallas=False)
-    )
-    got_pal = np.array(
-        ops.hash_encode(pts, w, proj, b_int, b_frac, width, use_pallas=True,
-                        interpret=True, bn=128, bb=64, bd=64)
-    )
-    u = (pts * w) @ proj / width + b_frac
-    assert _boundary_ok(got_pal - got_ref, u)
-    mismatch = np.mean(got_pal != got_ref)
-    assert mismatch < 1e-3  # boundary jitter must stay rare
-
-
-@pytest.mark.parametrize("shape", _SHAPES, ids=str)
-@pytest.mark.parametrize("c,n_levels", [(2, 10), (3, 7)])
-def test_freq_level_sweep(shape, c, n_levels):
-    n, d, beta, Q = shape
-    pts, qs, w, proj, b_int, b_frac = _mk(n, d, beta, Q, seed=1)
-    cp = np.array(ops.hash_encode(pts, w, proj, b_int, b_frac, 10.0,
-                                  use_pallas=False))
-    cq = np.array(ops.hash_encode(qs, w, proj, b_int, b_frac, 10.0,
-                                  use_pallas=False))
-    rng = np.random.default_rng(2)
-    mu = rng.integers(1, max(2, beta // 3), Q).astype(np.int32)
-    beta_q = rng.integers(1, beta + 1, Q).astype(np.int32)
-    got_ref = np.array(ops.freq_level(cp, cq, mu, c=c, n_levels=n_levels,
-                                      beta_q=beta_q, use_pallas=False))
-    got_pal = np.array(ops.freq_level(cp, cq, mu, c=c, n_levels=n_levels,
-                                      beta_q=beta_q, use_pallas=True,
-                                      interpret=True, bn=128))
-    np.testing.assert_array_equal(got_ref, got_pal)
-
-
 def test_freq_level_semantics_bruteforce():
     """ref.freq_level == brute-force per-level collision counting."""
     rng = np.random.default_rng(3)
@@ -110,8 +59,7 @@ def test_freq_level_semantics_bruteforce():
     cp = rng.integers(-(c**L), c**L, (n, beta)).astype(np.int32)
     cq = rng.integers(-(c**L), c**L, (Q, beta)).astype(np.int32)
     mu = rng.integers(1, 6, Q).astype(np.int32)
-    got = np.array(ops.freq_level(cp, cq, mu, c=c, n_levels=L,
-                                  use_pallas=False))
+    got = np.array(ref.freq_level_ref(cp, cq, mu, c=c, n_levels=L))
     for qi in range(Q):
         for pi in range(n):
             first = L + 1
@@ -132,9 +80,7 @@ def test_freq_level_monotone_in_mu():
     cq = rng.integers(0, 729, (4, 16)).astype(np.int32)
     prev = None
     for mu in (1, 3, 6, 12):
-        cur = np.array(
-            ops.freq_level(cp, cq, mu, c=3, n_levels=6, use_pallas=False)
-        )
+        cur = np.array(ref.freq_level_ref(cp, cq, mu, c=3, n_levels=6))
         if prev is not None:
             assert np.all(cur >= prev)
         prev = cur
@@ -152,23 +98,12 @@ def test_count_level_matches_numpy():
         np.testing.assert_array_equal(got, want)
 
 
-@pytest.mark.parametrize("shape", _SHAPES[:3], ids=str)
-@pytest.mark.parametrize("p", [0.5, 1.0, 1.5])
-def test_weighted_lp_sweep(shape, p):
-    n, d, beta, Q = shape
-    pts, qs, w, *_ = _mk(n, d, beta, Q, seed=6)
-    got_ref = np.array(ops.weighted_lp_dist(qs, pts, w, p, use_pallas=False))
-    got_pal = np.array(ops.weighted_lp_dist(qs, pts, w, p, use_pallas=True,
-                                            interpret=True, bn=128, bd=64))
-    np.testing.assert_allclose(got_ref, got_pal, rtol=2e-4, atol=2e-2)
-
-
 @pytest.mark.parametrize("p", [1.0, 2.0])
 def test_weighted_lp_vs_host_oracle(p):
     from repro.core.distances import weighted_lp_np
 
     pts, qs, w, *_ = _mk(150, 32, 8, 7, seed=7)
-    got = np.array(ops.weighted_lp_dist(qs, pts, w, p))
+    got = np.array(ref.weighted_lp_ref(qs, pts, w, p))
     want = np.stack([weighted_lp_np(pts, q, w.astype(np.float64), p)
                      for q in qs])
     np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-2)
@@ -178,48 +113,30 @@ def test_weighted_lp_vs_host_oracle(p):
 def test_weighted_lp_dtypes(dtype):
     pts, qs, w, *_ = _mk(64, 16, 4, 3, seed=8)
     got = np.array(
-        ops.weighted_lp_dist(
+        ref.weighted_lp_ref(
             jnp.asarray(qs, dtype), jnp.asarray(pts, dtype),
-            jnp.asarray(w, jnp.float32), 2.0, use_pallas=False,
+            jnp.asarray(w, jnp.float32), 2.0,
         )
     )
-    ref32 = np.array(ops.weighted_lp_dist(qs, pts, w, 2.0, use_pallas=False))
+    ref32 = np.array(ref.weighted_lp_ref(qs, pts, w, 2.0))
     tol = 1e-5 if dtype == jnp.float32 else 0.05
     np.testing.assert_allclose(got, ref32, rtol=tol, atol=tol * 1e3)
 
 
-@settings(max_examples=15)
-@given(
-    n=st.integers(8, 96),
-    beta=st.integers(2, 24),
-    q=st.integers(1, 6),
-    c=st.sampled_from([2, 3]),
-    seed=st.integers(0, 10_000),
-)
-def test_property_freq_level_pallas_equals_ref(n, beta, q, c, seed):
-    rng = np.random.default_rng(seed)
-    L = 5
-    cp = rng.integers(-(c**L) * 2, (c**L) * 2, (n, beta)).astype(np.int32)
-    cq = rng.integers(-(c**L) * 2, (c**L) * 2, (q, beta)).astype(np.int32)
-    mu = rng.integers(1, beta + 1, q).astype(np.int32)
-    a = np.array(ops.freq_level(cp, cq, mu, c=c, n_levels=L,
-                                use_pallas=False))
-    b = np.array(ops.freq_level(cp, cq, mu, c=c, n_levels=L, use_pallas=True,
-                                interpret=True, bn=64))
-    np.testing.assert_array_equal(a, b)
-
-
 # ------------------------------------------------- fused query block step
 
-# Smaller than _SHAPES: interpret mode runs the grid in Python, and the
-# fused kernel re-runs per p.  (257, 33, ...) keeps wrapper padding (row
-# and d non-multiples of bn=128) in the sweep.
+# Interpret mode runs the grid in Python -> keep shapes moderate.
+# (257, 33, ...) keeps wrapper padding (row and d non-multiples of
+# bn=128) in the sweep; (300, 40, 70, 9) has Q > 8; (512, 128, 64, 8)
+# spans several row tiles.
 _FUSED_SHAPES = [
     (64, 16, 24, 4),  # (n, d, beta, Q)
     (257, 33, 70, 3),
     (96, 128, 24, 3),  # d a lane multiple: the bit-exact p != 2 case
+    (300, 40, 70, 9),
+    (512, 128, 64, 8),
 ]
-_PS = [2.0, 1.0, 0.5]
+_PS = [2.0, 1.0, 0.5, 1.5]
 
 
 def _mk_fused(n, d, beta, Q, seed=0):
@@ -345,12 +262,12 @@ def test_fused_n_live_skips_padding_rows(n_live, kind, p):
 
 
 def test_fused_ref_matches_unfused_stages():
-    """The fused XLA composite vs the seed-era separate stages.
+    """The fused XLA composite vs its stages run one by one.
 
-    Pins the bit-exact-by-construction property the engine relies on:
-    same distance helpers, same shapes -> identical bins 0..L and
-    identical stop-masked scores (dead-row parking differs only in bins
-    the stop logic never reads: unfused L+1 vs fused's sliced-off L+2).
+    Pins the composite, block by block, to the ref.py stage oracles it is
+    built from (``freq_level_ref``, ``per_query_dist``, the good-level
+    ceil and the stop mask): identical bins 0..L and identical
+    stop-masked scores, with rows past ``n_valid`` excluded.
     """
     n, d, beta, Q = 300, 40, 70, 9
     c, L = 2, 8
@@ -362,9 +279,9 @@ def test_fused_ref_matches_unfused_stages():
         hf, hg = ops.fused_query_block(
             cp, pts, cq, qs, qw, mu, r_min, beta_q, boff=0, n_valid=n_valid,
             c=c, n_levels=L, p=p, use_pallas=False)
-        lf = np.array(ops.freq_level(cp, cq, mu, c=c, n_levels=L,
-                                     beta_q=beta_q, use_pallas=False))
-        # the engine's unfused stage runs under jit too; eager op-by-op
+        lf = np.array(ref.freq_level_ref(cp, cq, mu, c=c, n_levels=L,
+                                         beta_q=beta_q))
+        # the composite runs the distance under jit; eager op-by-op
         # execution rounds the p = 2 expansion differently in the last ulp
         dist = np.array(jax.jit(ref.per_query_dist, static_argnums=3)(
             jnp.asarray(qs), jnp.asarray(qw), jnp.asarray(pts), p))
@@ -556,9 +473,9 @@ def test_hash_encode_matches_host_family():
                            center_weight=wc, ratio_cap=1e5, c=3, seed=2)
     want = hash_codes_np(pts, fam)
     got = np.array(
-        ops.hash_encode(
-            pts, fam.center_weight, fam.proj, fam.b_int, fam.b_frac,
-            fam.width, use_pallas=False,
+        ref.hash_encode_ref(
+            pts, fam.proj, fam.b_int, fam.b_frac, fam.center_weight,
+            fam.width,
         )
     )
     diff = got - want

@@ -54,7 +54,7 @@ def test_sharded_engine_matches_host_oracle_on_8_devices():
             n=len(data), d=16, beta=built.fam.beta, q_batch=4, k=3,
             c=3, n_levels=int(np.max(built.plan.n_levels)), p=2.0,
             block_n=128, gamma_n=cfg.gamma_n,
-            vec_dtype="float32", use_pallas=False,
+            vec_dtype="float32",
         )
         state = build_state(mesh, icfg, data, built.fam)
         step = make_query_step(mesh, icfg)

@@ -105,17 +105,17 @@ def test_fused_kernel_compiles_at_cell_shapes(one_chip, kind, beta, d, p,
 
 
 def test_query_step_compiles_with_the_pallas_kernels(topo, monkeypatch):
-    """The whole jitted query step on one described chip, with the default
-    (auto) kernel path resolved as it is on a TPU backend; its inputs end
-    with the launch's live-row count ``n_live``."""
+    """The whole jitted query step on one described chip, with the scan
+    mode read as it is on a TPU backend; its inputs end with the launch's
+    live-row count ``n_live``."""
     monkeypatch.setattr(kplatform, "_backend_cache", "tpu")
-    assert kplatform.resolve(None).label == "fused-pallas"
+    assert kplatform.scan_mode() == "pallas"
     mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
                 axis_types=(AxisType.Auto,) * 2)
     n = 16 * ROWS
     cfg = IndexConfig(n=n, d=128, beta=512, q_batch=Q, k=10, c=3,
                       n_levels=16, p=2.0, block_n=n, gamma_n=100.0,
-                      vec_dtype="float32", use_pallas=None)
+                      vec_dtype="float32")
     step = make_query_step(mesh, cfg)
     specs = query_input_specs(cfg)
     assert list(specs)[-1] == "n_live"
