@@ -149,8 +149,8 @@ def _rows_per_device(svc) -> list[dict[int, int]]:
     per_group = []
     for gi in range(svc.plan.n_groups):
         with svc.state_cache.lease(gi) as state:
-            for field in (state.codes, state.points):
-                rows = {s.device.id: s.data.shape[0]
+            for field in (state.codes, state.points):  # (w, n): point axis
+                rows = {s.device.id: s.data.shape[1]
                         for s in field.addressable_shards}
                 if len(rows) != svc.mesh.size or set(rows.values()) != {want}:
                     raise RuntimeError(

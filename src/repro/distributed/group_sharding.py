@@ -1,7 +1,7 @@
 """Sharded big-group serving: one group's rows across the device mesh.
 
-A single table group's ``QueryState`` (codes ``(n, beta)`` + vectors
-``(n, d)``) is the unit the serving stack pages, and until this layer it
+A single table group's ``QueryState`` (codes ``(beta, n)`` + vectors
+``(d, n)``, point axis minor) is the unit the serving stack pages, and until this layer it
 had to fit one device.  This module makes the row dimension a first-class
 mesh axis end to end:
 
@@ -92,9 +92,9 @@ def serving_mesh(n_shards: int = 1, *, axis: str = "data") -> Mesh:
 def state_shardings(mesh: Mesh, cfg):
     """Strict per-field shardings of one group's resident ``QueryState``.
 
-    Row-carrying fields (codes, points) shard over every mesh axis via
-    the "rows" logical-name rule; the folded family and the scalars are
-    replicated.  ``strict=True`` is the sharded-serving contract: a row
+    Row-carrying fields (codes, points) shard their point axis, the
+    minor one, over every mesh axis via the "rows" logical-name rule;
+    the folded family and the scalars are replicated.  ``strict=True`` is the sharded-serving contract: a row
     capacity that does not divide the mesh raises instead of silently
     replicating the state onto every device (``Batcher.row_capacity``
     rounds capacities to a mesh-size multiple precisely so this never
@@ -102,11 +102,11 @@ def state_shardings(mesh: Mesh, cfg):
     """
     from ..index.engine import QueryState  # deferred: engine imports us
 
-    rows = functools.partial(named_sharding, mesh, ("rows", None),
+    rows = functools.partial(named_sharding, mesh, (None, "rows"),
                              strict=True)
     return QueryState(
-        codes=rows(shape=(cfg.n, cfg.beta)),
-        points=rows(shape=(cfg.n, cfg.d)),
+        codes=rows(shape=(cfg.beta, cfg.n)),
+        points=rows(shape=(cfg.d, cfg.n)),
         proj=named_sharding(mesh, (None, None)),
         b_int=named_sharding(mesh, (None,)),
         b_frac=named_sharding(mesh, (None,)),
@@ -192,12 +192,13 @@ def host_row_ranges(capacity: int, n_shards: int) -> list[tuple[int, int]]:
 
 def _from_row_chunks(mesh: Mesh, chunks: list[np.ndarray],
                      sharding: NamedSharding, dtype) -> jax.Array:
-    """Assemble a row-sharded device array from per-shard host chunks."""
-    n_loc = chunks[0].shape[0]
-    shape = (n_loc * len(chunks),) + chunks[0].shape[1:]
+    """Assemble a point-sharded (w, n) device array from per-shard host
+    chunks, each (w, n_loc) or its transposed view."""
+    n_loc = chunks[0].shape[1]
+    shape = (chunks[0].shape[0], n_loc * len(chunks))
     arrs = []
     for dev, idx in sharding.addressable_devices_indices_map(shape).items():
-        start = idx[0].start or 0
+        start = idx[1].start or 0
         arrs.append(
             jax.device_put(np.asarray(chunks[start // n_loc], dtype), dev)
         )
@@ -259,7 +260,8 @@ def build_group_state_per_host(
                 cods[:m] = builder.pad_cols(
                     gplan.codes[lo:lo + m], cfg.beta
                 ).astype(np.int32)
-            vecs = np.asarray(jnp.asarray(pts).astype(vec_dt))
+            # transposed views: the transfer lays out (w, n_loc) itself
+            cods, vecs = cods.T, np.asarray(pts, vec_dt).T
         else:
             if encode is None:
                 encode = jax.jit(functools.partial(
@@ -312,10 +314,10 @@ class HostShardedState:
 
 
 def _row_chunks(arr: jax.Array) -> list[np.ndarray]:
-    """Per-shard host copies of a row-sharded array, replicas deduped."""
+    """Per-shard host copies of a point-sharded array, replicas deduped."""
     by_start: dict[int, np.ndarray] = {}
     for s in arr.addressable_shards:
-        start = s.index[0].start or 0
+        start = s.index[1].start or 0
         if start not in by_start:
             by_start[start] = np.asarray(s.data)
     return [by_start[start] for start in sorted(by_start)]
@@ -350,12 +352,12 @@ def restore_state_sharded(mesh: Mesh, host: HostShardedState):
     """
     from ..index.engine import QueryState
 
-    rows = functools.partial(named_sharding, mesh, ("rows", None),
+    rows = functools.partial(named_sharding, mesh, (None, "rows"),
                              strict=True)
-    n_codes = sum(c.shape[0] for c in host.codes)
-    n_pts = sum(c.shape[0] for c in host.points)
-    sh_codes = rows(shape=(n_codes, host.codes[0].shape[1]))
-    sh_pts = rows(shape=(n_pts, host.points[0].shape[1]))
+    n_codes = sum(c.shape[1] for c in host.codes)
+    n_pts = sum(c.shape[1] for c in host.points)
+    sh_codes = rows(shape=(host.codes[0].shape[0], n_codes))
+    sh_pts = rows(shape=(host.points[0].shape[0], n_pts))
     return QueryState(
         codes=_from_row_chunks(mesh, host.codes, sh_codes,
                                host.codes[0].dtype),

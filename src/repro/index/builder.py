@@ -8,6 +8,13 @@ never touches them:
 
     proj_folded = diag(W_center) @ A / w
     codes       = floor(x @ proj_folded + b_frac) + b_int
+
+States are stored point axis minor, codes (beta, n) and vectors (d, n)
+(``engine.QueryState``).  The device encode transposes inside its own
+program.  Host rows (n, w) go to the transfer as their transposed view
+(``_put_point_minor``), so the transfer's layout pass, which the chip's
+compact layout needs in either orientation, writes the state's layout
+directly and no device buffer is held twice.
 """
 
 from __future__ import annotations
@@ -40,7 +47,7 @@ __all__ = [
 ]
 
 # Row-capacity padding fill for host-code builds: a fixed sentinel code
-# (the same convention ops.py pads with) and zero vectors.  Dead rows are
+# and zero vectors.  Dead rows are
 # masked out of the query step by ``QueryState.n_valid``, so the fill only
 # has to be deterministic — every build path over the same live rows must
 # produce bit-identical states.
@@ -61,6 +68,7 @@ def fold_center_weight(fam: LpFamilyParams) -> dict[str, np.ndarray]:
 
 
 def _build_fn(points, proj, b_int, b_frac, vec_dtype):
+    """(n, d) points -> (beta, n) codes and (d, n) vectors."""
     codes = ref.hash_encode_ref(
         points.astype(jnp.float32),
         proj,
@@ -69,20 +77,31 @@ def _build_fn(points, proj, b_int, b_frac, vec_dtype):
         jnp.ones((points.shape[1],), jnp.float32),
         1.0,
     )
-    return codes, points.astype(vec_dtype)
+    return codes.T, points.astype(vec_dtype).T
+
+
+def _put_point_minor(rows: np.ndarray, sharding, dtype) -> jax.Array:
+    """Upload host rows (n, w) as the point-axis-minor (w, n) state field.
+
+    The transfer receives the transposed view and lays it out on the
+    device itself, as it already must for the chip's compact layout.
+    """
+    return jax.device_put(np.asarray(rows, dtype).T, sharding)
 
 
 def make_build_step(mesh: Mesh, cfg: IndexConfig):
-    """jit'd sharded build: (points, proj, b_int, b_frac) -> (codes, vectors)."""
+    """jit'd sharded build: (points, proj, b_int, b_frac) -> (codes, vectors),
+    points (n, d) in, codes (beta, n) and vectors (d, n) out."""
     pa = _point_axes(mesh)
     rows = NamedSharding(mesh, P(pa, None))
+    cols = NamedSharding(mesh, P(None, pa))
     rep2 = NamedSharding(mesh, P(None, None))
     rep1 = NamedSharding(mesh, P(None))
     fn = functools.partial(_build_fn, vec_dtype=jnp.dtype(cfg.vec_dtype))
     return jax.jit(
         fn,
         in_shardings=(rows, rep2, rep1, rep1),
-        out_shardings=(rows, rows),
+        out_shardings=(cols, cols),
     )
 
 
@@ -123,11 +142,11 @@ def build_state(
 
 
 def _state_shardings(mesh: Mesh) -> QueryState:
-    """Per-field shardings of a resident QueryState (rows over all axes)."""
+    """Per-field shardings of a resident QueryState (points over all axes)."""
     pa = _point_axes(mesh)
     return QueryState(
-        codes=NamedSharding(mesh, P(pa, None)),
-        points=NamedSharding(mesh, P(pa, None)),
+        codes=NamedSharding(mesh, P(None, pa)),
+        points=NamedSharding(mesh, P(None, pa)),
         proj=NamedSharding(mesh, P(None, None)),
         b_int=NamedSharding(mesh, P(None)),
         b_frac=NamedSharding(mesh, P(None)),
@@ -253,7 +272,7 @@ def build_group_state(
     b_int = pad_cols(folded["b_int"], cfg.beta)
     b_frac = pad_cols(folded["b_frac"], cfg.beta)
     pa = _point_axes(mesh)
-    rows = NamedSharding(mesh, P(pa, None))
+    cols = NamedSharding(mesh, P(None, pa))
     rep2 = NamedSharding(mesh, P(None, None))
     rep1 = NamedSharding(mesh, P(None))
     rep0 = NamedSharding(mesh, P())
@@ -299,10 +318,8 @@ def build_group_state(
             points = np.concatenate([
                 points, np.zeros((pad_rows, cfg.d), np.float32)
             ], axis=0)
-        codes = jax.device_put(jnp.asarray(codes_np, jnp.int32), rows)
-        vecs = jax.device_put(
-            jnp.asarray(points).astype(jnp.dtype(cfg.vec_dtype)), rows
-        )
+        codes = _put_point_minor(codes_np, cols, np.int32)
+        vecs = _put_point_minor(points, cols, jnp.dtype(cfg.vec_dtype))
     else:
         if pad_rows:
             points = np.concatenate([
@@ -363,7 +380,8 @@ def append_to_state(
 ) -> QueryState:
     """Splice sealed rows into a group state's reserved capacity.
 
-    Writes ``m`` new rows at ``state.n_valid`` and returns a state with
+    Writes ``m`` new rows (columns of the point-axis-minor state) at
+    ``state.n_valid`` and returns a state with
     ``n_valid`` advanced — codes/vector buffers keep their compiled
     (capacity) shapes, so the compaction that calls this never triggers a
     query-step recompile.  The update is functional (the input state stays
@@ -376,18 +394,16 @@ def append_to_state(
     if m != len(vectors):
         raise ValueError(f"codes/vectors row mismatch: {m} vs {len(vectors)}")
     off = int(state.n_valid)
-    cap = state.codes.shape[0]
+    cap = state.codes.shape[1]
     if off + m > cap:
         raise ValueError(
             f"append of {m} rows at {off} exceeds row capacity {cap} "
             f"(raise ServiceConfig.delta_reserve_rows)"
         )
-    codes_d = jnp.asarray(np.ascontiguousarray(codes, np.int32))
-    vecs_d = jnp.asarray(
-        np.ascontiguousarray(vectors, np.float32)
-    ).astype(state.points.dtype)
-    new_codes = jax.lax.dynamic_update_slice(state.codes, codes_d, (off, 0))
-    new_points = jax.lax.dynamic_update_slice(state.points, vecs_d, (off, 0))
+    codes_d = jnp.asarray(np.asarray(codes, np.int32).T)
+    vecs_d = jnp.asarray(np.asarray(vectors, state.points.dtype).T)
+    new_codes = jax.lax.dynamic_update_slice(state.codes, codes_d, (0, off))
+    new_points = jax.lax.dynamic_update_slice(state.points, vecs_d, (0, off))
     n_valid = jnp.asarray(off + m, jnp.int32)
     if mesh is not None:
         sh = _state_shardings(mesh)

@@ -116,8 +116,8 @@ class IndexConfig:
     def state_nbytes(self) -> int:
         """Device bytes of one group's resident ``QueryState`` at this config.
 
-        Accounts every array of the padded state — codes ``(n, beta)`` i32,
-        vectors ``(n, d)`` in ``vec_dtype``, the folded family
+        Accounts every array of the padded state — codes ``(beta, n)`` i32,
+        vectors ``(d, n)`` in ``vec_dtype`` (point axis minor), the folded family
         (``proj (d, beta)`` f32, ``b_int``/``b_frac (beta,)``, ``width ()``)
         plus the ``n_valid ()`` row-count scalar — so the serving
         ``StateCache`` can budget residency before a group is ever built.

@@ -1,10 +1,19 @@
 """Sharded WLSH query engine (the paper's Search).
 
-Point rows -- codes (n, beta) and vectors (n, d) -- are sharded over every
-mesh axis, and the query batch is replicated.  Each device scores all Q
-queries against its n/devices rows, so the (Q, n) work splits by rows
-while per-device state stays 1/devices of the index.  The only
-communication is
+A group's state is stored point axis minor: codes (beta, n) and vectors
+(d, n), with the row capacity n in whole 256-row kernel tiles on every
+shard (``Batcher.row_capacity``).  This is the chip's own compact layout
+for such arrays: the tables and dimensions go on sublanes and the points
+fill the 128 lanes, so HBM holds no more than the live rows padded to a
+tile, and the Pallas kernels read their (beta, 256) / (d, 256) tiles in
+place.  Stored the other way, (n, beta) with beta not a multiple of 128,
+the chip keeps the point axis minor anyway and every launch would
+transpose and pad the whole state into the kernels' tiles.
+
+The point axis is sharded over every mesh axis, and the query batch is
+replicated.  Each device scores all Q queries against its n/devices
+rows, so the (Q, n) work splits by rows while per-device state stays
+1/devices of the index.  The only communication is
 
   * a psum of per-query level histograms, (Q, L+2) ints, and
   * an all-gather of per-shard top-k rows, (Q, k),
@@ -68,16 +77,18 @@ __all__ = [
 class QueryState:
     """Device-resident table-group state (a pytree).
 
-    ``codes``/``points`` are materialized at the config's row *capacity*
-    (``IndexConfig.n``); ``n_valid`` counts the live rows.  Rows at or
+    ``codes``/``points`` are stored point axis minor, (beta, n) and
+    (d, n), the layout the chip keeps and the kernels tile in place (see
+    the module docstring).  They are materialized at the config's row
+    *capacity* (``IndexConfig.n``); ``n_valid`` counts the live rows.  Rows at or
     beyond ``n_valid`` are dead weight the query step masks out of both
     histogram passes, which is what lets streaming compaction append rows
     into reserved capacity without changing any compiled shape.  A static
     (non-streaming) build simply has ``n_valid == capacity``.
     """
 
-    codes: jax.Array  # (n, beta) int32, sharded (("pod","data"), None)
-    points: jax.Array  # (n, d) vec_dtype, sharded likewise
+    codes: jax.Array  # (beta, n) int32, points sharded over every axis
+    points: jax.Array  # (d, n) vec_dtype, sharded likewise
     proj: jax.Array  # (d, beta) f32, replicated
     b_int: jax.Array  # (beta,) int32, replicated
     b_frac: jax.Array  # (beta,) f32, replicated
@@ -102,8 +113,8 @@ def shardings(mesh: Mesh):
     pa = _point_axes(mesh)
     return {
         "state": QueryState(
-            codes=NamedSharding(mesh, P(pa, None)),
-            points=NamedSharding(mesh, P(pa, None)),
+            codes=NamedSharding(mesh, P(None, pa)),
+            points=NamedSharding(mesh, P(None, pa)),
             proj=NamedSharding(mesh, P(None, None)),
             b_int=NamedSharding(mesh, P(None)),
             b_frac=NamedSharding(mesh, P(None)),
@@ -132,21 +143,29 @@ def _query_shard(
     axis_sizes: tuple[int, ...],
 ):
     c, L, k = cfg.c, cfg.n_levels, cfg.k
-    n_loc = state.codes.shape[0]
+    n_loc = state.codes.shape[1]
     block = min(cfg.block_n, n_loc)
+    if n_loc % block:
+        raise ValueError(f"block_n {block} does not divide the shard's "
+                         f"{n_loc} rows; the tail would never be scanned")
     n_blocks = n_loc // block
     q_loc = queries.shape[0]
     qf32 = queries.astype(jnp.float32)
     wf32 = q_weight.astype(jnp.float32)
-
-    codes_blocks = state.codes.reshape(n_blocks, block, cfg.beta)
-    point_blocks = state.points.reshape(n_blocks, block, cfg.d)
 
     # Global row offsets per block: streaming states reserve row capacity
     # above the live count, and rows >= n_valid must vanish from both
     # passes (the block step excludes them from histograms and scores).
     shard_off = group_sharding.shard_row_offset(mesh_axes, axis_sizes, n_loc)
     boffs = shard_off + jnp.arange(n_blocks, dtype=jnp.int32) * block
+
+    def blocks(boff):
+        """The block's (beta, block) codes and (d, block) vectors, sliced
+        along the point axis; one block is the whole shard, untouched."""
+        off = boff - shard_off
+        return (jax.lax.dynamic_slice_in_dim(state.codes, off, block, 1),
+                jax.lax.dynamic_slice_in_dim(state.points, off, block, 1))
+
     n_valid = state.n_valid.astype(jnp.int32)
     block_step = functools.partial(
         ops.fused_query_block, codes_q=codes_q, queries=qf32,
@@ -155,15 +174,14 @@ def _query_shard(
     )
 
     # ---- pass 1: level histograms -> stop level ---------------------------
-    def pass1(carry, blk):
+    def pass1(carry, boff):
         hist_f, hist_g = carry
-        cb, pb, boff = blk
-        hf, hg = block_step(cb, pb, boff=boff)
+        hf, hg = block_step(*blocks(boff), boff=boff)
         return (hist_f + hf, hist_g + hg), None
 
     hist0 = jnp.zeros((q_loc, L + 2), jnp.int32)
     (hist_f, hist_g), _ = jax.lax.scan(
-        pass1, (hist0, hist0), (codes_blocks, point_blocks, boffs),
+        pass1, (hist0, hist0), boffs,
         unroll=n_blocks if cfg.analysis_unroll else 1,
     )
     hist_f, hist_g = group_sharding.merge_histograms(hist_f, hist_g,
@@ -183,10 +201,9 @@ def _query_shard(
     ).astype(jnp.int32)  # (q_loc,)
 
     # ---- pass 2: masked distances -> running local top-k ------------------
-    def pass2(carry, blk):
+    def pass2(carry, boff):
         vals, idx = carry
-        cb, pb, boff = blk
-        scores = block_step(cb, pb, boff=boff, stop=stop)
+        scores = block_step(*blocks(boff), boff=boff, stop=stop)
         bvals, bidx = jax.lax.top_k(-scores, k)
         bidx = bidx + boff
         vals = jnp.concatenate([vals, -bvals], axis=1)
@@ -199,7 +216,7 @@ def _query_shard(
         jnp.full((q_loc, k), -1, jnp.int32),
     )
     (vals, idx), _ = jax.lax.scan(
-        pass2, init, (codes_blocks, point_blocks, boffs),
+        pass2, init, boffs,
         unroll=n_blocks if cfg.analysis_unroll else 1,
     )
 
@@ -209,7 +226,8 @@ def _query_shard(
     # Recompute the survivors' distances from the coordinate differences
     # ((q_loc, k, d) work, exact in f32) and re-sort.
     local_rows = jnp.clip(idx - shard_off, 0, n_loc - 1)
-    cand = state.points[local_rows].astype(jnp.float32)  # (q_loc, k, d)
+    cand = ops.gather_columns(state.points, local_rows,
+                              n_live=n_live).astype(jnp.float32)
     diff = jnp.abs((qf32[:, None, :] - cand) * wf32[:, None, :])
     if abs(cfg.p - 2.0) < 1e-9:
         exact = jnp.sqrt(jnp.sum(diff * diff, axis=-1))
@@ -273,8 +291,8 @@ def make_query_step(mesh: Mesh, cfg: IndexConfig):
         mesh=mesh,
         in_specs=(
             QueryState(
-                codes=P(pa, None),
-                points=P(pa, None),
+                codes=P(None, pa),
+                points=P(None, pa),
                 proj=P(None, None),
                 b_int=P(None),
                 b_frac=P(None),
@@ -345,8 +363,8 @@ def query_input_specs(cfg: IndexConfig):
     """ShapeDtypeStructs for the dry-run (no allocation)."""
     vec = jnp.dtype(cfg.vec_dtype)
     state = QueryState(
-        codes=jax.ShapeDtypeStruct((cfg.n, cfg.beta), jnp.int32),
-        points=jax.ShapeDtypeStruct((cfg.n, cfg.d), vec),
+        codes=jax.ShapeDtypeStruct((cfg.beta, cfg.n), jnp.int32),
+        points=jax.ShapeDtypeStruct((cfg.d, cfg.n), vec),
         proj=jax.ShapeDtypeStruct((cfg.d, cfg.beta), jnp.float32),
         b_int=jax.ShapeDtypeStruct((cfg.beta,), jnp.int32),
         b_frac=jax.ShapeDtypeStruct((cfg.beta,), jnp.float32),

@@ -1,21 +1,27 @@
-"""The padded, jit-traced dispatch of one fused query block step.
+"""The jit-traced dispatch of one fused query block step.
 
 ``fused_query_block`` is the engine's per-scan-block launch (pass-1
 histograms or pass-2 stop-masked scores).  It runs the Pallas kernels in
 fused_query.py (compiled on TPU, or in interpret mode) or their fused XLA
 composite in ref.py; a caller that names neither gets the backend's
-``platform.scan_mode()``.  The Pallas route pads rows to the kernel tile
-and d to 128 lanes, and slices the padding back off.
+``platform.scan_mode()``.  Both routes take the block as the group state
+stores it, point axis minor: codes (beta, B) and vectors (d, B).  The
+Pallas route launches on it as it is, with nothing padded or transposed.
 """
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from . import platform, ref
-from .fused_query import fused_query_hist_pallas, fused_query_scores_pallas
+from .fused_query import (
+    ROW_TILE,
+    fused_query_hist_pallas,
+    fused_query_scores_pallas,
+)
 
-__all__ = ["fused_query_block"]
+__all__ = ["fused_query_block", "gather_columns"]
 
 
 def _kernel_flags(use_pallas, interpret):
@@ -26,19 +32,9 @@ def _kernel_flags(use_pallas, interpret):
     return use_pallas, interpret
 
 
-def _pad_to(x, mult: int, axis: int, value=0):
-    size = x.shape[axis]
-    rem = (-size) % mult
-    if rem == 0:
-        return x
-    pad = [(0, 0)] * x.ndim
-    pad[axis] = (0, rem)
-    return jnp.pad(x, pad, constant_values=value)
-
-
 def fused_query_block(
-    codes_p,  # (B, beta) int32 — one scan block of point codes
-    points,  # (B, d) — the matching vector block (any float dtype)
+    codes_p,  # (beta, B) int32 — one scan block of point codes
+    points,  # (d, B) — the matching vector block (any float dtype)
     codes_q,  # (Q, beta) int32 query bucket codes
     queries,  # (Q, d) query vectors
     q_weight,  # (Q, d) per-query weight vectors
@@ -55,18 +51,17 @@ def fused_query_block(
     n_live=None,  # () int32 live query rows (rows >= it are padding); None = Q
     use_pallas: bool | None = None,  # None = the backend's scan mode
     interpret: bool = False,  # with use_pallas=True: run the body interpreted
-    bn: int = 256,
+    bn: int = ROW_TILE,
     unroll: bool = False,
 ):
     """One fused query block step — the engine's per-scan-block launch.
 
     Pass 1 (``stop=None``) returns ``(hist_f, hist_g)`` per-level
     frequent/good histogram contributions, each ``(Q, n_levels + 2)``
-    int32 (bins 0..n_levels+1; excluded rows — block padding and rows at
-    or beyond ``n_valid`` — are dropped entirely).  Pass 2 (``stop``
-    given) returns ``(Q, B)`` f32 distances with rows past the query's
-    stop level (and excluded rows) masked to +inf, ready for a running
-    top-k.
+    int32 (bins 0..n_levels+1; excluded rows, those at or beyond
+    ``n_valid``, are dropped entirely).  Pass 2 (``stop`` given) returns
+    ``(Q, B)`` f32 distances with rows past the query's stop level (and
+    excluded rows) masked to +inf, ready for a running top-k.
 
     The XLA route is the fused composite in ref.py, built from the ref.py
     stage oracles (``freq_level_ref``, ``per_query_dist``).  The Pallas
@@ -75,12 +70,12 @@ def fused_query_block(
     histograms and +inf scores.  The XLA route ignores ``n_live``.
     """
     use_pallas, interpret = _kernel_flags(use_pallas, interpret)
-    b, _ = codes_p.shape
+    beta, b = codes_p.shape
     q = codes_q.shape[0]
     mu = jnp.broadcast_to(jnp.asarray(mu, jnp.int32), (q,))
     r_min = jnp.broadcast_to(jnp.asarray(r_min, jnp.float32), (q,))
     if beta_q is None:
-        beta_q = jnp.full((q,), codes_p.shape[1], jnp.int32)
+        beta_q = jnp.full((q,), beta, jnp.int32)
     beta_q = jnp.broadcast_to(jnp.asarray(beta_q, jnp.int32), (q,))
     if stop is not None:
         stop = jnp.broadcast_to(jnp.asarray(stop, jnp.int32), (q,))
@@ -103,20 +98,34 @@ def fused_query_block(
             c=c, n_levels=n_levels, p=p, unroll=unroll,
         )
 
-    cp = _pad_to(codes_p, bn, 0, value=jnp.iinfo(jnp.int32).max // 2)
-    xp = _pad_to(_pad_to(pts, bn, 0), 128, 1)
-    qsp = _pad_to(qs, 128, 1)
-    wp = _pad_to(w, 128, 1)
+    kw = dict(c=c, n_levels=n_levels, p=p, bn=bn, interpret=interpret,
+              n_live=n_live)
     if stop is None:
         hf, hg = fused_query_hist_pallas(
-            cp, xp, codes_q, qsp, wp, mu, beta_q, r_min, boff, n_valid,
-            c=c, n_levels=n_levels, p=p, n_rows=b, bn=bn,
-            interpret=interpret, n_live=n_live,
-        )
+            codes_p, pts, codes_q, qs, w, mu, beta_q, r_min, boff, n_valid,
+            **kw)
         return hf[:, : n_levels + 2], hg[:, : n_levels + 2]
-    out = fused_query_scores_pallas(
-        cp, xp, codes_q, qsp, wp, mu, beta_q, stop, boff, n_valid,
-        c=c, n_levels=n_levels, p=p, n_rows=b, bn=bn, interpret=interpret,
-        n_live=n_live,
-    )
-    return out[:, :b]
+    return fused_query_scores_pallas(
+        codes_p, pts, codes_q, qs, w, mu, beta_q, stop, boff, n_valid, **kw)
+
+
+def gather_columns(points, rows, *, n_live=None):
+    """``points[:, rows]`` as (q, k, d): the vectors of a (d, n) block's
+    columns ``rows`` (q, k), for the exact re-rank of the winners.
+
+    A loop reads one (d, 1) column per candidate of the live rows: a
+    gather of single columns makes the chip's compiler transpose the whole
+    (d, n) state first.  Rows at or past ``n_live`` are padding, scored
+    +inf, and read zeros here.
+    """
+    q, k = rows.shape
+    flat = rows.reshape(-1)
+    n_live = q if n_live is None else jnp.clip(n_live, 0, q)
+
+    def body(i, out):
+        col = jax.lax.dynamic_slice_in_dim(points, flat[i], 1, 1)
+        return jax.lax.dynamic_update_slice_in_dim(out, col, i, 1)
+
+    out = jax.lax.fori_loop(0, n_live * k, body,
+                            jnp.zeros((points.shape[0], q * k), points.dtype))
+    return out.T.reshape(q, k, -1)

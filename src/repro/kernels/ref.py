@@ -16,7 +16,8 @@ The fused-query oracles (``fused_query_hist_ref`` / ``fused_query_scores_ref``)
 define the semantics of one fused block step — first-frequent level, weighted
 distance, good-level histogramming and stop-mask scoring in one composite,
 built from the stage oracles above and ``per_query_dist``.  They are also
-the *serving* fused path off-TPU.
+the *serving* fused path off-TPU, so they take a block as the group state
+stores it, point axis minor: codes (beta, B) and vectors (d, B).
 """
 
 from __future__ import annotations
@@ -136,21 +137,27 @@ def log_c(x, c: int):
 
 
 def per_query_l2(q, w, pts):
-    """(Q, B) weighted l2 with per-query weights, via two matmuls (MXU)."""
+    """(Q, B) weighted l2 with per-query weights, via two matmuls (MXU).
+
+    ``pts`` is (d, B), point axis minor.
+    """
     w2 = w * w
     qw2 = jnp.sum(w2 * q * q, axis=-1)  # (Q,)
-    cross = jnp.matmul(w2 * q, pts.T, precision=_F32)  # (Q, B)
-    onorm = jnp.matmul(w2, (pts * pts).T, precision=_F32)  # (Q, B)
+    cross = jnp.matmul(w2 * q, pts, precision=_F32)  # (Q, B)
+    onorm = jnp.matmul(w2, pts * pts, precision=_F32)  # (Q, B)
     d2 = qw2[:, None] - 2.0 * cross + onorm
     return jnp.sqrt(jnp.maximum(d2, 0.0))
 
 
 def per_query_lp(q, w, pts, p: float):
-    """(Q, B) weighted l_p (p != 2) with per-query weights, elementwise."""
-    diff = jnp.abs((q[:, None, :] - pts[None, :, :]) * w[:, None, :])
+    """(Q, B) weighted l_p (p != 2) with per-query weights, elementwise.
+
+    ``pts`` is (d, B); the sum runs over d, the kernel's sublane axis.
+    """
+    diff = jnp.abs((q[:, :, None] - pts[None, :, :]) * w[:, :, None])
     if abs(p - 1.0) < 1e-9:
-        return jnp.sum(diff, axis=-1)
-    return jnp.sum(diff**p, axis=-1) ** (1.0 / p)
+        return jnp.sum(diff, axis=1)
+    return jnp.sum(diff**p, axis=1) ** (1.0 / p)
 
 
 def per_query_dist(q, w, pts, p: float):
@@ -168,12 +175,13 @@ def per_query_dist(q, w, pts, p: float):
 def _fused_lf(codes_b, codes_q, mu, beta_q, row_ok, c, n_levels, unroll):
     """(Q, B) first-frequent level with excluded rows forced to L + 2.
 
-    Excluded rows (padding or rows at/after the streaming ``n_valid``
-    watermark) get the sentinel ``n_levels + 2`` — past every histogram
-    bin the stop logic reads (0..n_levels) and past every reachable stop
-    level, so they vanish from both passes.
+    ``codes_b`` is (beta, B), as the state stores it.  Excluded rows
+    (rows at/after the streaming ``n_valid`` watermark) get the sentinel
+    ``n_levels + 2`` — past every histogram bin the stop logic reads
+    (0..n_levels) and past every reachable stop level, so they vanish
+    from both passes.
     """
-    lf = freq_level_ref(codes_b, codes_q, mu, c, n_levels, beta_q,
+    lf = freq_level_ref(codes_b.T, codes_q, mu, c, n_levels, beta_q,
                         unroll=unroll)
     return jnp.where(row_ok[None, :], lf, jnp.int32(n_levels + 2))
 

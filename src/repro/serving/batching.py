@@ -49,6 +49,7 @@ from ..index.builder import (
 )
 from ..index.config import IndexConfig, pad_beta, pad_levels
 from ..index.engine import QueryStepCache, encode_queries
+from ..kernels.fused_query import ROW_TILE
 from .qos import DegradeStep
 from .state_cache import StateCache
 
@@ -539,12 +540,15 @@ class Batcher:
 
         ``ServiceConfig.delta_reserve_rows`` preallocates headroom that
         streaming compaction appends into without changing any compiled
-        shape; the capacity is rounded up to a mesh-size multiple so the
-        row sharding stays even.  All groups share one capacity, which
-        preserves the shape-bucket compiled-step sharing.
+        shape.  The capacity is rounded up to whole kernel tiles
+        (``kernels.fused_query.ROW_TILE`` rows) on every shard, so the row
+        sharding stays even, each launch runs whole tiles, and the
+        point-axis-minor state is in the chip's compact layout.  All groups
+        share one capacity, which preserves the shape-bucket compiled-step
+        sharing.
         """
         cap = self.plan.n + self.cfg.delta_reserve_rows
-        return cap + (-cap) % self.mesh.size
+        return cap + (-cap) % (self.mesh.size * ROW_TILE)
 
     def _block_n(self) -> int:
         n_loc = self.row_capacity() // self.mesh.size

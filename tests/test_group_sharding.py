@@ -142,10 +142,12 @@ _PARITY_SETUP = """
 
     assert jax.device_count() == 8
     P_VAL = %(p)s
-    # 1003 live rows: ragged under every shard count > 1.  The 5 reserve
-    # rows pad the shared capacity to 1008 = 16 * 63, so every shard
-    # count runs identical (q, 63, d) block gemms and bit-exactness is
-    # structural, not luck (f32 matmuls are shape-sensitive).
+    # 1003 live rows: ragged under every shard count > 1.  The capacity
+    # is whole 256-row tiles per shard (1024 rows for 1 and 2 shards,
+    # 2048 for 8), and block_n=63 divides every shard's slice down to
+    # 32-row blocks, so every shard count runs identical (q, 32, d) block
+    # gemms and bit-exactness is structural, not luck (f32 matmuls are
+    # shape-sensitive).
     data = make_dataset(n=1003, d=16, seed=41)
     weights = make_weight_set(size=8, d=16, n_subset=4, n_subrange=10,
                               seed=42)
@@ -231,9 +233,13 @@ def test_sharded_offload_restore_roundtrip_per_shard():
         before_codes = np.asarray(st.codes)
         before_pts = np.asarray(st.points, np.float32)
         host = offload_state_sharded(st)
+    # 1003 rows in whole 256-row tiles on each of 8 shards
+    cap = svc.batcher.row_capacity()
+    assert cap == 8 * 256
     assert len(host.codes) == 8 and len(host.points) == 8
-    assert all(c.shape[0] == 1008 // 8 for c in host.codes)
-    np.testing.assert_array_equal(np.concatenate(host.codes), before_codes)
+    assert all(c.shape[1] == cap // 8 for c in host.codes)
+    np.testing.assert_array_equal(np.concatenate(host.codes, axis=1),
+                                  before_codes)
     restored = restore_state_sharded(svc.mesh, host)
     np.testing.assert_array_equal(np.asarray(restored.codes), before_codes)
     np.testing.assert_array_equal(
@@ -241,7 +247,7 @@ def test_sharded_offload_restore_roundtrip_per_shard():
     assert int(restored.n_valid) == 1003
     # the restored placement is the strict row sharding (8 distinct rows
     # slices, nothing replicated)
-    starts = {s.index[0].start or 0 for s in restored.codes.addressable_shards}
+    starts = {s.index[1].start or 0 for s in restored.codes.addressable_shards}
     assert len(starts) == 8
     print("OK")
     """)
@@ -269,8 +275,10 @@ def test_per_host_build_matches_materialized_build():
 
     hosted = build_group_state(svc.mesh, cfg, None, gplan,
                                points_loader=loader, n_points=len(data))
-    assert len(calls) >= 8 - 1  # per-range calls (dead tail range skipped)
-    assert all(hi - lo <= 1008 // 8 for lo, hi in calls), calls
+    # one call per shard range that holds live rows (dead ranges skipped)
+    n_loc = svc.batcher.row_capacity() // 8
+    assert len(calls) == -(-len(data) // n_loc), calls
+    assert all(hi - lo <= n_loc for lo, hi in calls), calls
     np.testing.assert_array_equal(np.asarray(hosted.codes),
                                   np.asarray(whole.codes))
     np.testing.assert_array_equal(np.asarray(hosted.points, np.float32),

@@ -193,6 +193,20 @@ def test_engine_fused_paths_bit_exact(setup, monkeypatch, block_n):
         )
 
 
+def test_engine_refuses_a_block_that_does_not_divide_the_shard(setup):
+    """A block_n that leaves a tail of the shard's rows unscanned is refused
+    when the step is traced, not answered wrongly."""
+    data, weights, cfg, host, mesh = setup
+    gi = int(host.part.group_of[0])
+    icfg, state, _, built = _engine_for_group(host, mesh, gi, data, k=5)
+    icfg = dataclasses.replace(icfg, block_n=384)
+    wids = [int(w) for w in built.plan.member_ids[:4]]
+    args = (*_step_args(host, built, state, data, wids, seed=59),
+            jnp.int32(len(wids)))
+    with pytest.raises(ValueError, match="does not divide"):
+        jax.eval_shape(make_query_step(mesh, icfg), state, *args)
+
+
 @pytest.mark.parametrize("n_live", [1, 3])
 def test_engine_n_live_answers_live_rows_as_a_full_batch(setup, monkeypatch,
                                                         n_live):
@@ -268,8 +282,11 @@ def test_build_is_deterministic(setup):
     # codes agree with the host planner's (float64) oracle except at rare
     # f32-vs-f64 floor boundaries (projection magnitudes reach ~r_max/w, so
     # f32 ulp jitter near bucket edges flips ~0.5% of codes by exactly one —
-    # noise on top of the random hash, bounded and harmless)
-    host_codes = built.codes
+    # noise on top of the random hash, bounded and harmless); the state
+    # stores them point axis minor, (beta, n)
+    assert s1.codes.shape == (built.fam.beta, len(data))
+    assert s1.points.shape == (data.shape[1], len(data))
+    host_codes = built.codes.T
     mismatch = np.mean(np.asarray(s1.codes) != host_codes)
     assert mismatch < 2e-2
     assert np.max(np.abs(np.asarray(s1.codes) - host_codes)) <= 1

@@ -2,11 +2,11 @@
 themselves vs brute force and the host planner.
 
 Per the kernel contract, fused_query_block's Pallas body against the
-fused XLA composite: histograms exact-int; scores carry an identical
-+inf stop-mask and are bit-exact for p != 2 when d is already a lane
-multiple (no padding), else ulp-tight allclose — padding d changes the
-f32 reduction tree, and the p = 2 in-body MXU expansion may differ from
-the XLA gemm in the last ulp.  (Serving bit-exactness does not rest on
+fused XLA composite, both on blocks stored point axis minor (codes
+(beta, B), vectors (d, B)): histograms exact-int; scores carry an
+identical +inf stop-mask and are bit-exact for p != 2, where both sum
+over d in order, and ulp-tight allclose at p = 2, where the in-body MXU
+expansion may differ from the XLA gemm in the last ulp.  (Serving bit-exactness does not rest on
 this: off-TPU the served scan is the XLA composite itself.)  The hash
 oracle matches the planner's float64 codes except at floor boundaries,
 where f32 and f64 summation orders may differ by one bucket.
@@ -126,13 +126,13 @@ def test_weighted_lp_dtypes(dtype):
 # ------------------------------------------------- fused query block step
 
 # Interpret mode runs the grid in Python -> keep shapes moderate.
-# (257, 33, ...) keeps wrapper padding (row and d non-multiples of
-# bn=128) in the sweep; (300, 40, 70, 9) has Q > 8; (512, 128, 64, 8)
-# spans several row tiles.
+# (257, 33, ...) keeps a partial last tile (rows not a multiple of
+# bn=128) and d not a multiple of 8 in the sweep; (300, 40, 70, 9) has
+# Q > 8; (512, 128, 64, 8) spans several row tiles.
 _FUSED_SHAPES = [
     (64, 16, 24, 4),  # (n, d, beta, Q)
     (257, 33, 70, 3),
-    (96, 128, 24, 3),  # d a lane multiple: the bit-exact p != 2 case
+    (96, 128, 24, 3),  # d a lane multiple
     (300, 40, 70, 9),
     (512, 128, 64, 8),
 ]
@@ -140,6 +140,8 @@ _PS = [2.0, 1.0, 0.5, 1.5]
 
 
 def _mk_fused(n, d, beta, Q, seed=0):
+    """Point codes (n, beta) and vectors (n, d) row-major, as the oracles
+    take them; the block step gets their transposes."""
     rng = np.random.default_rng(seed)
     cp = rng.integers(-(2**16), 2**16, (n, beta)).astype(np.int32)
     cq = rng.integers(-(2**16), 2**16, (Q, beta)).astype(np.int32)
@@ -161,11 +163,11 @@ def _fused_both(shape, p, *, boff, n_valid, stop=None, seed=0, bn=128):
     if stop is not None:
         stop = st_
     kw = dict(boff=boff, n_valid=n_valid, c=2, n_levels=8, p=p, stop=stop)
-    got_ref = ops.fused_query_block(cp, pts, cq, qs, qw, mu, r_min, beta_q,
-                                    use_pallas=False, **kw)
-    got_pal = ops.fused_query_block(cp, pts, cq, qs, qw, mu, r_min, beta_q,
-                                    use_pallas=True, interpret=True, bn=bn,
-                                    **kw)
+    got_ref = ops.fused_query_block(cp.T, pts.T, cq, qs, qw, mu, r_min,
+                                    beta_q, use_pallas=False, **kw)
+    got_pal = ops.fused_query_block(cp.T, pts.T, cq, qs, qw, mu, r_min,
+                                    beta_q, use_pallas=True, interpret=True,
+                                    bn=bn, **kw)
     return got_ref, got_pal
 
 
@@ -194,10 +196,8 @@ def test_fused_scores_pallas_equals_ref(shape, p):
     np.testing.assert_array_equal(fin, np.isfinite(s1))  # same stop mask
     if abs(p - 2.0) < 1e-9:
         np.testing.assert_allclose(s0[fin], s1[fin], rtol=2e-4, atol=2e-2)
-    elif shape[1] % 128 == 0:
-        np.testing.assert_array_equal(s0[fin], s1[fin])  # bit-exact, no pad
-    else:  # d-padding changes the f32 reduction tree: ulp-tight only
-        np.testing.assert_allclose(s0[fin], s1[fin], rtol=1e-5, atol=1e-3)
+    else:  # both sum over d in order, at every d
+        np.testing.assert_array_equal(s0[fin], s1[fin])
 
 
 @pytest.mark.parametrize("p", _PS)
@@ -229,14 +229,14 @@ def test_fused_n_live_skips_padding_rows(n_live, kind, p):
     """With ``n_live`` < Q the kernels answer the live rows exactly as the
     full grid does, and rows at or past it read zero histograms / +inf
     scores.  Live rows also match the XLA reference: histograms and p = 1
-    scores (d a lane multiple) bit for bit, p = 2 scores as tightly as
+    scores bit for bit, p = 2 scores as tightly as
     ``test_fused_scores_pallas_equals_ref`` holds them."""
     n, d, beta, Q = 200, 128, 40, 8
     cp, cq, pts, qs, qw, mu, beta_q, r_min, stop = _mk_fused(
         n, d, beta, Q, seed=21)
     kw = dict(boff=0, n_valid=n - 9, c=2, n_levels=8, p=p,
               stop=stop if kind == "scores" else None)
-    args = (cp, pts, cq, qs, qw, mu, r_min, beta_q)
+    args = (cp.T, pts.T, cq, qs, qw, mu, r_min, beta_q)
     want = ops.fused_query_block(*args, use_pallas=False, **kw)
     full = ops.fused_query_block(*args, use_pallas=True, interpret=True,
                                  bn=128, **kw)
@@ -277,14 +277,14 @@ def test_fused_ref_matches_unfused_stages():
     row_ok = np.arange(n) < n_valid
     for p in _PS:
         hf, hg = ops.fused_query_block(
-            cp, pts, cq, qs, qw, mu, r_min, beta_q, boff=0, n_valid=n_valid,
-            c=c, n_levels=L, p=p, use_pallas=False)
+            cp.T, pts.T, cq, qs, qw, mu, r_min, beta_q, boff=0,
+            n_valid=n_valid, c=c, n_levels=L, p=p, use_pallas=False)
         lf = np.array(ref.freq_level_ref(cp, cq, mu, c=c, n_levels=L,
                                          beta_q=beta_q))
         # the composite runs the distance under jit; eager op-by-op
         # execution rounds the p = 2 expansion differently in the last ulp
         dist = np.array(jax.jit(ref.per_query_dist, static_argnums=3)(
-            jnp.asarray(qs), jnp.asarray(qw), jnp.asarray(pts), p))
+            jnp.asarray(qs), jnp.asarray(qw), jnp.asarray(pts.T), p))
         jg = np.ceil(np.maximum(
             np.log(np.maximum(dist, 1e-30)) / np.log(c)
             - np.log(c * r_min)[:, None] / np.log(c), 0.0)).astype(np.int64)
@@ -294,8 +294,9 @@ def test_fused_ref_matches_unfused_stages():
                 want = ((bins == j) & row_ok[None, :]).sum(axis=1)
                 np.testing.assert_array_equal(fused[:, j], want)
         scores = np.array(ops.fused_query_block(
-            cp, pts, cq, qs, qw, mu, r_min, beta_q, boff=0, n_valid=n_valid,
-            c=c, n_levels=L, p=p, stop=stop, use_pallas=False))
+            cp.T, pts.T, cq, qs, qw, mu, r_min, beta_q, boff=0,
+            n_valid=n_valid, c=c, n_levels=L, p=p, stop=stop,
+            use_pallas=False))
         want = np.where((lf <= stop[:, None]) & row_ok[None, :], dist, np.inf)
         np.testing.assert_array_equal(scores, want)  # bit-exact, shared HLO
 
@@ -305,9 +306,9 @@ def test_fused_scalar_broadcast_and_default_beta():
     n, d, beta, Q = 64, 16, 24, 4
     cp, cq, pts, qs, qw, *_ = _mk_fused(n, d, beta, Q, seed=12)
     kw = dict(boff=0, n_valid=n, c=2, n_levels=8, p=1.0)
-    a = ops.fused_query_block(cp, pts, cq, qs, qw, 3, 50.0, None,
+    a = ops.fused_query_block(cp.T, pts.T, cq, qs, qw, 3, 50.0, None,
                               use_pallas=False, **kw)
-    b = ops.fused_query_block(cp, pts, cq, qs, qw,
+    b = ops.fused_query_block(cp.T, pts.T, cq, qs, qw,
                               np.full(Q, 3, np.int32),
                               np.full(Q, 50.0, np.float32),
                               np.full(Q, beta, np.int32),
@@ -376,9 +377,9 @@ def test_fused_levels_exact_at_int32_edges(c, n_levels, mode):
     else:
         beta_q = rng.integers(beta // 2, beta, Q).astype(np.int32)
         boff, n_valid = 500, 500 + n - 37
-    # p = 1 at a lane-multiple d: the distances are bit-exact too
+    # p = 1: the distances are bit-exact too
     kw = dict(boff=boff, n_valid=n_valid, c=c, n_levels=n_levels, p=1.0)
-    args = (cp, pts, cq, qs, qw, mu, r_min, beta_q)
+    args = (cp.T, pts.T, cq, qs, qw, mu, r_min, beta_q)
     hf0, hg0 = ops.fused_query_block(*args, use_pallas=False, **kw)
     hf1, hg1 = ops.fused_query_block(*args, use_pallas=True, interpret=True,
                                      bn=128, **kw)
@@ -406,33 +407,40 @@ def _eqns(jaxpr):
                     yield from _eqns(sub)
 
 
-@pytest.mark.parametrize("kind", ["hist", "scores"])
-def test_fused_kernel_divides_no_point_tile(kind):
-    """No integer division or remainder inside the fused kernel body acts
-    on the (bn, beta) point-code tile: the level test divides only the
-    (1, beta) query row."""
+def _pass_jaxpr(kind, rows, bn, beta, d, Q, L, **kw):
+    """The jaxpr of one fused pass on a point-axis-minor block."""
     from repro.kernels import fused_query as fq
 
-    rows, bn, beta, d, Q, L = 512, 256, 480, 128, 8, 14
     fn = {"hist": fq.fused_query_hist_pallas,
           "scores": fq.fused_query_scores_pallas}[kind]
     per_q = jnp.zeros((Q,), jnp.int32)
-    r_min_or_stop = jnp.zeros((Q,), jnp.float32 if kind == "hist" else
-                              jnp.int32)
-    jaxpr = jax.make_jaxpr(functools.partial(
-        fn, c=3, n_levels=L, p=2.0, n_rows=rows, bn=bn))(
-        jnp.zeros((rows, beta), jnp.int32), jnp.zeros((rows, d)),
+    last = jnp.zeros((Q,), jnp.float32 if kind == "hist" else jnp.int32)
+    return jax.make_jaxpr(functools.partial(
+        fn, c=3, n_levels=L, p=2.0, bn=bn, **kw))(
+        jnp.zeros((beta, rows), jnp.int32), jnp.zeros((d, rows)),
         jnp.zeros((Q, beta), jnp.int32), jnp.zeros((Q, d)),
-        jnp.zeros((Q, d)), per_q, per_q, r_min_or_stop, jnp.int32(0),
-        jnp.int32(0))
+        jnp.zeros((Q, d)), per_q, per_q, last, jnp.int32(0), jnp.int32(0))
+
+
+@pytest.mark.parametrize("kind", ["hist", "scores"])
+def test_fused_kernel_divides_no_point_tile(kind):
+    """No integer division or remainder inside the fused kernel body, so
+    none acts on the (beta, bn) point-code tile: the level bounds come from
+    dividing the (Q, beta) query codes once per launch, outside it."""
+    rows, bn, beta, d, Q, L = 512, 256, 480, 128, 8, 14
+    jaxpr = _pass_jaxpr(kind, rows, bn, beta, d, Q, L)
     calls = [e for e in _eqns(jaxpr.jaxpr)
              if e.primitive.name == "pallas_call"]
     assert len(calls) == 1
-    divs = [e for e in _eqns(calls[0].params["jaxpr"])
-            if e.primitive.name in ("div", "rem")]
-    shapes = {tuple(v.aval.shape) for e in divs for v in e.outvars}
-    assert (1, beta) in shapes  # the query row's bucket chain is seen
-    assert (bn, beta) not in shapes
+
+    def int_divs(eqns):
+        return [e for e in eqns if e.primitive.name in ("div", "rem")
+                and jnp.issubdtype(e.outvars[0].aval.dtype, jnp.integer)]
+
+    assert not int_divs(_eqns(calls[0].params["jaxpr"]))
+    outside = int_divs(_eqns(jaxpr.jaxpr))
+    shapes = {tuple(v.aval.shape) for e in outside for v in e.outvars}
+    assert shapes == {(Q, beta)}  # the query rows' bucket chain
 
 
 @pytest.mark.parametrize("kind", ["hist", "scores"])
@@ -440,19 +448,8 @@ def test_fused_grid_query_bound_is_run_time(kind):
     """The grid is (n_live, n_tiles): the query bound is read at run time
     (one dynamic bound, so ``n_live`` adds no compiled shape) and the
     tile axis is the block's, so a full batch runs Q x n_tiles steps."""
-    from repro.kernels import fused_query as fq
-
     rows, bn, beta, d, Q, L = 512, 256, 480, 128, 8, 14
-    fn = {"hist": fq.fused_query_hist_pallas,
-          "scores": fq.fused_query_scores_pallas}[kind]
-    per_q = jnp.zeros((Q,), jnp.int32)
-    last = jnp.zeros((Q,), jnp.float32 if kind == "hist" else jnp.int32)
-    jaxpr = jax.make_jaxpr(functools.partial(
-        fn, c=3, n_levels=L, p=2.0, n_rows=rows, bn=bn))(
-        jnp.zeros((rows, beta), jnp.int32), jnp.zeros((rows, d)),
-        jnp.zeros((Q, beta), jnp.int32), jnp.zeros((Q, d)),
-        jnp.zeros((Q, d)), per_q, per_q, last, jnp.int32(0), jnp.int32(0),
-        n_live=jnp.int32(Q))
+    jaxpr = _pass_jaxpr(kind, rows, bn, beta, d, Q, L, n_live=jnp.int32(Q))
     (eqn,) = [e for e in _eqns(jaxpr.jaxpr)
               if e.primitive.name == "pallas_call"]
     grid_mapping = eqn.params["grid_mapping"]

@@ -78,6 +78,46 @@ def test_mixed_batch_matches_search_dense(parity_setup):
         )
 
 
+@pytest.mark.parametrize("mode", ["xla", "interpret"])
+def test_capacity_in_whole_tiles_answers_exactly(monkeypatch, mode):
+    """A 1,000-row corpus (not a whole 256-row tile) is stored point axis
+    minor at a capacity of 1,024 rows, (beta, 1024) codes and (16, 1024)
+    vectors with 24 dead rows past ``n_valid``, and both scan routes (the
+    CPU composite and the Pallas kernels, interpreted) answer bit for bit
+    as ``search_dense`` does: ids, stop levels and n_checked."""
+    from repro.core.datagen import make_dataset, make_weight_set
+    from repro.core.params import PlanConfig
+    from repro.core.wlsh import WLSHIndex
+    from repro.kernels import platform as kplatform
+
+    monkeypatch.setattr(kplatform, "scan_mode", lambda: mode)
+    data = make_dataset(n=1_000, d=16, seed=41)
+    weights = make_weight_set(size=8, d=16, n_subset=4, n_subrange=10,
+                              seed=42)
+    host = WLSHIndex(data, weights,
+                     PlanConfig(p=2.0, c=3, n=len(data), gamma_n=100.0),
+                     tau=500.0, v=4, v_prime=4, seed=9)
+    plan = host.export_serving_plan()
+    svc = RetrievalService(plan, data, cfg=ServiceConfig(k=K, q_batch=4))
+    assert plan.n == 1_000 and svc.batcher.row_capacity() == 1_024
+    qpts, wids = _mixed_queries(data, weights, 12)
+    res = svc.query(qpts, wids)
+    assert len(np.unique(res.group_ids)) >= 2
+    for gi in np.unique(res.group_ids):
+        with svc.state_cache.lease(int(gi)) as st:
+            assert st.codes.shape == (svc.group_config(int(gi)).beta, 1_024)
+            assert st.points.shape == (16, 1_024)
+            assert int(st.n_valid) == 1_000
+    for qi in range(len(qpts)):
+        want = host.search_dense(qpts[qi], weight_id=int(wids[qi]), k=K)
+        np.testing.assert_array_equal(res.ids[qi], want.ids.astype(np.int32))
+        assert int(res.stop_levels[qi]) == want.stats.stop_level
+        assert int(res.n_checked[qi]) == want.stats.n_checked
+        m = res.ids[qi] >= 0
+        np.testing.assert_allclose(res.dists[qi][m], want.dists[m],
+                                   rtol=1e-4, atol=1e-2)
+
+
 def test_one_compiled_step_per_shape_signature(setup):
     data, weights, host, plan, svc = setup
     svc.warmup()  # every group built + compiled

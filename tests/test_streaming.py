@@ -216,7 +216,11 @@ def test_capacity_exhaustion_is_explicit(setup):
     data, weights, host, plan, _ = setup
     svc = _streaming_service(plan, data, reserve=4, seal_rows=2)
     w_in = int(plan.groups[0].member_ids[0])
-    pids = [svc.insert(_far_vector(data, j, 9), w_in) for j in range(6)]
+    # the capacity holds the reserve rounded up to a whole 256-row tile
+    room = svc.batcher.row_capacity() - plan.n
+    assert 4 <= room < 4 + 256
+    pids = [svc.insert(_far_vector(data, j, 9), w_in)
+            for j in range(room + 2)]
     # the background (non-strict) path skips the over-capacity group...
     assert svc.batcher.delta.compact_sealed() == 0
     # ...while the explicit path names the fix
@@ -493,7 +497,9 @@ def test_failed_purge_commits_nothing(setup):
     svc = _streaming_service(plan, data, seal_rows=2, reserve=4)
     gi = int(np.argmax([g.n_members for g in plan.groups]))
     w_in = int(plan.groups[gi].member_ids[0])
-    pids = [svc.insert(_far_vector(data, j, 31), w_in) for j in range(6)]
+    room = svc.batcher.row_capacity() - plan.n
+    pids = [svc.insert(_far_vector(data, j, 31), w_in)
+            for j in range(room + 2)]
     svc.delete(0)  # a base tombstone so the purge can't degrade to compact
     with pytest.raises(ValueError, match="delta_reserve_rows"):
         svc.compact(purge=True)

@@ -10,6 +10,8 @@ a fixture, so collecting this file never loads the TPU library.
 
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -21,6 +23,7 @@ from repro.index.config import IndexConfig
 from repro.index.engine import make_query_step, query_input_specs
 from repro.kernels import ops
 from repro.kernels import platform as kplatform
+from repro.kernels.fused_query import ROW_TILE
 
 Q, BN, ROWS = 8, 256, 4096
 
@@ -57,7 +60,8 @@ def one_chip(topo):
 
 
 def _compile_fused(one_chip, kind, beta, d, p, n_levels, rows=ROWS):
-    """Compiled HLO text of one fused pass through the ops wrapper, with
+    """Compiled HLO text of one fused pass through the ops wrapper on a
+    point-axis-minor block, codes (beta, rows) and vectors (d, rows), with
     the launch's live-row count ``n_live`` a run-time scalar (the grid's
     dynamic query bound), as the query step passes it."""
 
@@ -73,7 +77,7 @@ def _compile_fused(one_chip, kind, beta, d, p, n_levels, rows=ROWS):
             use_pallas=True, interpret=False, bn=BN)
 
     return jax.jit(step).lower(
-        spec((rows, beta), jnp.int32), spec((rows, d), jnp.float32),
+        spec((beta, rows), jnp.int32), spec((d, rows), jnp.float32),
         spec((Q, beta), jnp.int32), spec((Q, d), jnp.float32),
         spec((Q, d), jnp.float32), spec((Q,), jnp.int32),
         spec((Q,), jnp.float32), spec((Q,), jnp.int32),
@@ -132,3 +136,61 @@ def test_fused_kernel_custom_call_carries_its_name(one_chip, kind):
     calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
     assert calls and all(
         ln.lstrip().startswith(f"%fused_query_{kind}.") for ln in calls)
+
+
+def _own_relayouts(hlo: str, extent: int) -> list[str]:
+    """Copies, pads and transposes that run as device ops of their own
+    (not inside a fusion) and have a dimension of at least ``extent``."""
+    fused = set(re.findall(r"\bfusion\(.*?calls=(%[\w.\-]+)", hlo))
+    found, own = [], True
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.\-]+) \(", line)
+        if head:
+            own = head.group(1) not in fused
+        m = re.search(r"= (\S+) (copy|copy-start|copy-done|pad|transpose)\(",
+                      line)
+        if own and m and any(int(x) >= extent for x in
+                             re.findall(r"\d+", m.group(1).split("{")[0])):
+            found.append(line.strip()[:160])
+    return found
+
+
+def _transposed_state(hlo: str, cap: int, beta: int, d: int) -> list[str]:
+    """Instructions anywhere in the program, fusion bodies included, that
+    hold the whole state with the point axis major: shaped s32[cap,beta]
+    or f32[cap,d], or laid out {0,1} as s32[beta,cap] or f32[d,cap].  That
+    is a relayout of the state into row tiles, wherever the compiler put
+    it."""
+    shape = re.compile(
+        rf"\b(?:s32\[{cap},{beta}\]|f32\[{cap},{d}\]"
+        rf"|s32\[{beta},{cap}\]\{{0,1\b|f32\[{d},{cap}\]\{{0,1\b)")
+    return [ln.strip()[:160] for ln in hlo.splitlines() if shape.search(ln)]
+
+
+@pytest.mark.parametrize("n,beta,d,p,n_levels", [
+    (200_000, 480, 128, 2.0, 16),  # sift128_p2, groups 0 and 1
+    (200_000, 256, 128, 2.0, 16),  # sift128_p2, groups 2 and 3
+    (100_000, 416, 960, 1.0, 20),  # gist960_p1
+], ids=["sift128_p2-beta480", "sift128_p2-beta256", "gist960_p1"])
+def test_query_step_reads_the_state_in_place(topo, monkeypatch, n, beta, d,
+                                             p, n_levels):
+    """At the benchmark cells' state shapes the whole query step takes the
+    point-axis-minor state in row-major layout, as the chip keeps it, and
+    no copy, pad or transpose of the state's row extent runs per launch,
+    nor any op, fused or not, on the state transposed to points major:
+    the kernels read the state where it lies."""
+    monkeypatch.setattr(kplatform, "_backend_cache", "tpu")
+    cap = n + (-n) % ROW_TILE  # Batcher.row_capacity on one chip
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    cfg = IndexConfig(n=cap, d=d, beta=beta, q_batch=Q, k=10, c=3,
+                      n_levels=n_levels, p=p, block_n=cap, gamma_n=100.0,
+                      vec_dtype="float32")
+    compiled = make_query_step(mesh, cfg).lower(
+        *query_input_specs(cfg).values()).compile()
+    state = compiled.input_formats[0][0]
+    assert state.codes.layout.major_to_minor == (0, 1)
+    assert state.points.layout.major_to_minor == (0, 1)
+    hlo = compiled.as_text()
+    assert _own_relayouts(hlo, cap) == []
+    assert _transposed_state(hlo, cap, beta, d) == []
